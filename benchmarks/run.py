@@ -22,6 +22,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.common import enable_compilation_cache
+
+    enable_compilation_cache()
     from benchmarks import (
         fig7_device_dse,
         fig7c_arch_dse,

@@ -65,6 +65,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common import enable_compilation_cache
 from repro.gnn import build_model, load
 from repro.gnn.train import train_graph_classifier
 from repro.photonic.perf import GhostConfig, GnnModelSpec
@@ -178,6 +179,7 @@ def main():
         ap.error("--seeds-per-query must be >= 1")
     if args.pipeline_depth < 0:
         ap.error("--pipeline-depth must be >= 0")
+    enable_compilation_cache()
     if args.node_queries:
         run_node_queries(args)
         return

@@ -34,9 +34,7 @@ import numpy as np
 
 
 def _flatten(tree):
-    # jax.tree.flatten_with_path only exists on newer jax; tree_util's
-    # spelling works across the versions this repo supports.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in p)
              for p, _ in flat]
     return paths, [v for _, v in flat], treedef

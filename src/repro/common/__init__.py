@@ -1,4 +1,5 @@
 from repro.common.utils import (
+    enable_compilation_cache,
     cdiv,
     round_up,
     tree_size,
@@ -8,6 +9,7 @@ from repro.common.utils import (
 )
 
 __all__ = [
+    "enable_compilation_cache",
     "cdiv",
     "round_up",
     "tree_size",

@@ -2,9 +2,30 @@
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# JAX's persistent compilation cache, unless JAX_COMPILATION_CACHE_DIR
+# places it elsewhere: one fixed directory in the checkout (git-ignored), so
+# a later run from the same checkout finds what an earlier one compiled.
+COMPILATION_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
+
+
+def enable_compilation_cache() -> str:
+    """Turn JAX's persistent compilation cache on; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has read it already and
+    this leaves the setting alone.  Entry points call this; library code
+    and tests do not.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILATION_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
 
 
 def cdiv(a: int, b: int) -> int:

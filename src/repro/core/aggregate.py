@@ -341,6 +341,11 @@ def aggregate_blocked(
 ) -> jax.Array:
     """Blocked aggregation over non-zero tiles only.
 
+    The Pallas backends run SUM and MEAN on the ``block_spmm`` kernel.  MAX
+    has no unfused kernel: on every backend it is computed by the jnp
+    comparator below (the fused kernel's comparator mode is reached only
+    through ``aggregate_combine_blocked`` on ``pallas_fused``).
+
     Args:
       bg: blocked adjacency (non-zero tiles).
       feat_padded: [G_src * N, F] source features, padded (see
@@ -437,30 +442,29 @@ class CombinePlan(NamedTuple):
         return dict(self._asdict())
 
 
-_PLAN_TLS = threading.local()
-
-
-def _plan_log() -> dict:
-    log = getattr(_PLAN_TLS, "log", None)
-    if log is None:
-        log = _PLAN_TLS.log = {}
-    return log
+# Process-wide, not per thread: the always-on serve loop traces executors
+# in its worker threads, and a report reads the log from another thread.
+_PLAN_LOCK = threading.Lock()
+_PLAN_LOG: dict = {}
 
 
 def planner_decisions() -> list:
     """Order decisions recorded at trace time, one dict per distinct
     (tile geometry, F_in, F_out, reduce, backend) site — benchmark/report
     fodder, deduplicated so jit retraces don't grow it."""
+    with _PLAN_LOCK:
+        items = list(_PLAN_LOG.items())
     return [
         {"blocks": k[0], "v": k[1], "n": k[2], "g_dst": k[3], "g_src": k[4],
          "f_in": k[5], "f_out": k[6], "reduce": k[7], "backend": k[8],
          "quantized": k[9], **plan.to_dict()}
-        for k, plan in _plan_log().items()
+        for k, plan in items
     ]
 
 
 def clear_planner_log() -> None:
-    _plan_log().clear()
+    with _PLAN_LOCK:
+        _PLAN_LOG.clear()
 
 
 def _order_flops(b: int, v: int, n: int, g_dst: int, g_src: int,
@@ -510,7 +514,8 @@ def _record_plan(bg: BlockedGraph, f_in: int, f_out: int, reduce: ReduceOp,
     key = (int(bg.blocks.shape[0]), bg.v, bg.n, bg.num_dst_groups,
            bg.num_src_groups, f_in, f_out, str(reduce.value), backend,
            bool(quantized))
-    _plan_log()[key] = plan
+    with _PLAN_LOCK:
+        _PLAN_LOG[key] = plan
 
 
 # The one epilogue-activation vocabulary, shared with the fused kernel

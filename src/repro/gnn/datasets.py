@@ -19,6 +19,7 @@ All generation is seeded and deterministic.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Sequence
 
 import numpy as np
@@ -39,6 +40,12 @@ TABLE2 = {
 
 NODE_CLASSIFICATION = ("Cora", "PubMed", "Citeseer", "Amazon")
 GRAPH_CLASSIFICATION = ("Proteins", "Mutag", "BZR", "IMDB-binary")
+
+
+def _name_seed(name: str, seed: int) -> int:
+    """Per-dataset rng seed.  ``zlib.crc32``, not ``hash``: Python salts
+    str hashes per process, which made every process draw other graphs."""
+    return seed + zlib.crc32(name.encode()) % 65536
 
 
 def _planted_features(rng, labels, num_features, signal=1.0, noise=1.0,
@@ -87,7 +94,7 @@ def _dc_sbm_edges(rng, labels, num_edges, p_in=0.85):
 
 def make_node_classification(name: str, seed: int = 0) -> Graph:
     spec = TABLE2[name]
-    rng = np.random.default_rng(seed + hash(name) % 65536)
+    rng = np.random.default_rng(_name_seed(name, seed))
     n, e = spec["nodes"], spec["edges"]
     labels = rng.integers(0, spec["labels"], size=n).astype(np.int32)
     src, dst = _dc_sbm_edges(rng, labels, e)
@@ -141,7 +148,7 @@ def _pa_tree(rng, n, extra=2):
 def make_graph_classification(name: str, seed: int = 0,
                               num_graphs: int | None = None) -> list[Graph]:
     spec = TABLE2[name]
-    rng = np.random.default_rng(seed + hash(name) % 65536)
+    rng = np.random.default_rng(_name_seed(name, seed))
     count = num_graphs or spec["graphs"]
     avg_n, avg_e, f = spec["nodes"], spec["edges"], spec["features"]
     graphs = []

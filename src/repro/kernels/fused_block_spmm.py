@@ -136,16 +136,18 @@ def _kernel(block_row, block_col, blocks_ref, feat_ref, w_ref, bias_ref,
         def _init():
             acc_ref[...] = jnp.full_like(acc_ref, -jnp.inf)
 
-        # Optical-comparator merge: masked per-tile feature max, then a
-        # running maximum across the row's tiles.  Multiplicity does not
-        # enter MAX, only edge presence.
-        mask = blocks_ref[...] != 0                                # [V, N]
-        cand = jnp.where(
-            mask[:, :, None],
-            feat_ref[...][None, :, :].astype(jnp.float32),         # [1,N,F]
-            -jnp.inf,
-        )
-        acc_ref[...] = jnp.maximum(acc_ref[...], cand.max(axis=1))
+        # Optical-comparator merge: a running maximum over the tile's
+        # source columns, one [V, F] masked candidate per column (Mosaic
+        # lowers these 2-D selects; it refuses the [V, N, 1] mask a 3-D
+        # candidate needs).  Multiplicity does not enter MAX, only edge
+        # presence.
+        tile = blocks_ref[...]                                     # [V, N]
+        feat = feat_ref[...].astype(jnp.float32)                   # [N, F]
+        acc = acc_ref[...]
+        for j in range(tile.shape[1]):
+            acc = jnp.maximum(acc, jnp.where(tile[:, j:j + 1] != 0,
+                                             feat[j:j + 1, :], -jnp.inf))
+        acc_ref[...] = acc
     else:
         @pl.when(first_visit)
         def _init():

@@ -1,12 +1,20 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode — the
-kernel body executes in Python with the same block/grid semantics, which is
-how correctness is validated offline.  On TPU backends the compiled kernels
-run natively.  ``auto_interpret()`` picks per backend.
+Off the TPU the kernels run in ``interpret=True`` mode — the kernel body
+executes in Python with the same block/grid semantics, which is how
+correctness is validated on a CPU.  On TPU backends the compiled kernels
+run natively.  ``auto_interpret()`` picks per backend; code that must prove
+it ran on the chip checks the compiled program for ``tpu_custom_call``
+(see ``chip_smoke.py``).
 
 The wrappers also handle padding to tile multiples so callers can pass
-arbitrary shapes.
+arbitrary shapes: feature and weight dims to the 128-wide lane, and the
+tile dims V and N to the 8-row sublane (``SUBLANE``).  The TPU lowering
+refuses a block whose second-minor dim is not a multiple of 8, and the
+adjacency tile's V and N are exactly such dims of the feature, degree and
+output blocks.  Padded tile rows and columns are zero, which adds nothing
+to SUM/MEAN and means "no edge" to MAX, and the padded output rows are
+sliced off again.
 """
 
 from __future__ import annotations
@@ -31,6 +39,10 @@ def auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# Second-minor (sublane) multiple the TPU lowering requires of a block.
+SUBLANE = 8
+
+
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     size = x.shape[axis]
     pad = (-size) % multiple
@@ -39,6 +51,30 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _pad_groups(x: jax.Array, group: int) -> jax.Array:
+    """[G*group, ...] -> [G*group8, ...]: zero rows appended to every group
+    of ``group`` rows, so each group fills a whole number of sublanes."""
+    if group % SUBLANE == 0:
+        return x
+    g = x.reshape(-1, group, *x.shape[1:])
+    return _pad_to(g, 1, SUBLANE).reshape(-1, *x.shape[1:])
+
+
+def _unpad_groups(x: jax.Array, group: int) -> jax.Array:
+    """Inverse of ``_pad_groups``: keep the first ``group`` rows of each
+    sublane-padded group."""
+    if group % SUBLANE == 0:
+        return x
+    padded = group + (-group) % SUBLANE
+    g = x.reshape(-1, padded, *x.shape[1:])[:, :group]
+    return g.reshape(-1, *x.shape[1:])
+
+
+def _pad_tiles(blocks: jax.Array) -> jax.Array:
+    """[B, V, N] -> [B, V8, N8] with zero rows and columns."""
+    return _pad_to(_pad_to(blocks, 1, SUBLANE), 2, SUBLANE)
 
 
 @functools.partial(jax.jit, static_argnames=("num_dst_groups", "block_f", "interpret"))
@@ -51,17 +87,18 @@ def block_spmm_padded(
     block_f: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """block_spmm with automatic feature-dim padding.  Returns [G_dst*V, F]."""
+    """block_spmm with feature-dim and tile padding.  Returns [G_dst*V, F]."""
     interpret = auto_interpret() if interpret is None else interpret
     f = feat.shape[1]
-    featp = _pad_to(feat, 1, block_f)
+    v, n = blocks.shape[1:]
+    featp = _pad_groups(_pad_to(feat, 1, block_f), n)
     out = block_spmm(
-        blocks, block_row, block_col, featp, num_dst_groups,
+        _pad_tiles(blocks), block_row, block_col, featp, num_dst_groups,
         block_f=block_f, interpret=interpret,
     )
+    out = _unpad_groups(out, v)
     # Destination groups with no tiles are never visited by the kernel, so
     # their output blocks are uninitialized; zero them here.
-    v = blocks.shape[1]
     visited = jnp.zeros((num_dst_groups,), jnp.bool_).at[block_row].set(True)
     out = jnp.where(jnp.repeat(visited, v)[:, None], out, 0.0)
     return out[:, :f]
@@ -87,13 +124,15 @@ def fused_block_spmm_padded(
     lane: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """fused_block_spmm with lane padding + unvisited-row patch-up.
+    """fused_block_spmm with lane and tile padding + unvisited-row patch-up.
 
     Pads F_in/F_out to ``lane`` multiples (zero feature columns x zero
-    weight rows contribute nothing; padded output columns are sliced off),
-    runs the fused kernel, and rewrites never-visited destination groups to
-    ``act(bias)`` — the value the unfused oracle assigns to an all-zero
-    aggregation row.  ``quantized`` quantizes the float weights here
+    weight rows contribute nothing; padded output columns are sliced off)
+    and V/N to sublane multiples (zero tile rows/columns; padded output
+    rows are sliced off), runs the fused kernel, and rewrites never-visited
+    destination groups to ``act(bias)`` — the value the unfused oracle
+    assigns to an all-zero aggregation row.  ``quantized`` quantizes the
+    float weights here
     (per-output-channel, identical to ``photonic.quant.quantize_weights``)
     and selects the int8 sign-split combine epilogue; see the kernel
     docstring for its documented tolerance vs the per-tensor-scale oracle.
@@ -101,14 +140,15 @@ def fused_block_spmm_padded(
     """
     interpret = auto_interpret() if interpret is None else interpret
     f_in, f_out = w.shape
-    v = blocks.shape[1]
-    featp = _pad_to(feat, 1, lane)
+    v, n = blocks.shape[1:]
+    featp = _pad_groups(_pad_to(feat, 1, lane), n)
     bias_row = (jnp.zeros((f_out,), feat.dtype) if bias is None
                 else bias.astype(feat.dtype))
     biasp = _pad_to(bias_row.reshape(1, f_out), 1, lane)
     apply_deg = inv_deg is not None
     invd = (jnp.ones((num_dst_groups * v, 1), feat.dtype) if not apply_deg
             else inv_deg.reshape(num_dst_groups * v, 1).astype(feat.dtype))
+    invd = _pad_groups(invd, v)
 
     w_scale = None
     if quantized:
@@ -122,10 +162,11 @@ def fused_block_spmm_padded(
         wp = _pad_to(_pad_to(w, 0, lane), 1, lane)
 
     out = fused_block_spmm(
-        blocks, block_row, block_col, featp, wp, biasp, invd,
+        _pad_tiles(blocks), block_row, block_col, featp, wp, biasp, invd,
         num_dst_groups, activation=activation, apply_deg=apply_deg,
         reduce=reduce, w_scale=w_scale, interpret=interpret,
-    )[:, :f_out]
+    )
+    out = _unpad_groups(out, v)[:, :f_out]
     # Destination groups with no tiles are never visited by the kernel, so
     # their output blocks are uninitialized; the oracle maps their all-zero
     # aggregation rows through the epilogue, i.e. to act(bias) (a zero row

@@ -22,26 +22,18 @@ from __future__ import annotations
 import jax
 
 
-def _auto_axis_types(num_axes: int) -> dict:
-    # jax.sharding.AxisType only exists on newer jax; older versions treat
-    # every mesh axis as Auto already, so omitting the kwarg is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(devices_per_axis: tuple[int, ...] = (2, 2),
                     axes: tuple[str, ...] = ("data", "model")):
     """Small mesh for CPU-host tests (requires matching device count)."""
     return jax.make_mesh(devices_per_axis, axes,
-                         **_auto_axis_types(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

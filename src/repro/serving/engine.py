@@ -290,6 +290,7 @@ class GnnServeEngine:
         quantized models stay single-device inside the scope because their
         per-tensor activation scale is a global reduction).  The trace key
         is effectively (model_id, bucket, mesh) — one pool is one mesh.
+        A 1-device mesh shards nothing and pins the engine to its device.
       shard_axis: mesh axis name the feature partition maps onto.
     """
 
@@ -768,10 +769,10 @@ class GnnServeEngine:
             # not time spent queued behind a peer's execution.
             t_exec0 = time.perf_counter()
             exe = self.pool.executor(entry, bucket)
-            out = exe(entry.params, jnp.asarray(sb.blocks),
-                      jnp.asarray(sb.rows), jnp.asarray(sb.cols),
-                      jnp.asarray(sb.feats))
-            out = np.asarray(jax.block_until_ready(out))
+            out = jax.block_until_ready(exe(*self.pool.device_args(
+                entry, sb.blocks, sb.rows, sb.cols, sb.feats)))
+            devices = tuple(sorted(d.id for d in out.devices()))
+            out = np.asarray(out)
             t_done = time.perf_counter()
             exec_s = t_done - t_exec0
 
@@ -811,6 +812,7 @@ class GnnServeEngine:
                 sampled_nodes=p.sampled_nodes,
                 sampled_edges=p.sampled_edges,
                 fanouts=p.fanouts_desc,
+                devices=devices,
             ))
         with self._cond:
             # Publish in extraction order within the group: concurrent

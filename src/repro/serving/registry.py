@@ -259,9 +259,15 @@ class ExecutorPool:
         # The pool's device topology: every trace it builds is keyed by
         # (model_id, bucket) *within* this mesh — a pool IS one mesh, so
         # the effective trace key is (model_id, bucket, mesh).  A 1-device
-        # mesh is equivalent to no mesh (the shard router is a no-op).
+        # mesh shards nothing but pins the pool to its device: params and
+        # batches are placed there (see ``device_args``), which is how
+        # router replicas each own a chip.
         self.mesh = mesh
         self.shard_axis = shard_axis
+        self.device = (mesh.devices.flat[0]
+                       if mesh is not None and mesh.devices.size == 1
+                       else None)
+        self._placed_params: dict[str, object] = {}
         self._executors: dict[tuple[str, Bucket], Callable] = {}
         self._trace_count = 0
         # Pipelined serving runs several executor workers; the get-or-build
@@ -281,12 +287,15 @@ class ExecutorPool:
         """Mesh topology baked into this pool's traces (report surface)."""
         if self.mesh is None:
             return {}
-        return {
+        topo = {
             "num_devices": self.num_shards,
             "mesh_shape": {a: int(s) for a, s in self.mesh.shape.items()},
             "shard_axis": self.shard_axis,
             "strategy": "feature" if self.num_shards > 1 else "none",
         }
+        if self.device is not None:
+            topo["device"] = int(self.device.id)
+        return topo
 
     def kernel_configs(self) -> dict:
         """Shape-class -> config resolved so far (report surface)."""
@@ -304,6 +313,43 @@ class ExecutorPool:
 
     def __len__(self) -> int:
         return len(self._executors)
+
+    def keys(self) -> list[tuple[str, Bucket]]:
+        """The (model_id, bucket) pairs that have an executor."""
+        with self._lock:
+            return list(self._executors)
+
+    def device_args(self, entry: ModelEntry, *batch) -> tuple:
+        """``(params, *batch)`` as executor arguments, on the pool's device.
+
+        A model's params are placed once, every batch on each call.  A
+        pinned pool (1-device mesh) commits them to its device; otherwise
+        ``self.device`` is None and they go to JAX's default device, where
+        a multi-device mesh shards them inside the trace.
+        """
+        params = self._placed_params.get(entry.model_id)
+        if params is None:
+            params = self._placed_params.setdefault(
+                entry.model_id, jax.device_put(entry.params, self.device))
+        return (params, *jax.device_put(batch, self.device))
+
+    def lower(self, entry: ModelEntry, bucket: Bucket, sharding=None):
+        """Lower the (model, bucket) executor at its batch shapes without
+        running it; ``.compile().as_text()`` then shows what the device
+        runs.  ``sharding`` places the abstract arguments, e.g. on a
+        described (not attached) device for an ahead-of-time compile."""
+        def struct(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        r = self.slots
+        params = jax.tree.map(lambda p: struct(p.shape, p.dtype),
+                              entry.params)
+        return self.executor(entry, bucket).lower(
+            params,
+            struct((r, bucket.num_blocks, bucket.v, bucket.n), jnp.float32),
+            struct((r, bucket.num_blocks), jnp.int32),
+            struct((r, bucket.num_blocks), jnp.int32),
+            struct((r, bucket.padded_src, bucket.f), jnp.float32))
 
     def executor(self, entry: ModelEntry, bucket: Bucket) -> Callable:
         key = (entry.model_id, bucket)
@@ -325,7 +371,8 @@ class ExecutorPool:
         # pooling at the bucket shape would break bit-exactness).
         num_nodes = min(bucket.padded_dst, bucket.padded_src)
         # A 1-device mesh is a no-op shard scope; None suppresses sharding
-        # entirely, so the trace is identical to the meshless pool's.
+        # entirely, so the trace is identical to the meshless pool's (the
+        # pin to its device happens in device_args, outside the trace).
         shard_mesh = self.mesh if self.num_shards > 1 else None
         shard_axis = self.shard_axis
 

@@ -52,6 +52,7 @@ class RequestRecord:
     sampled_nodes: int = 0     # real vertices in the sampled subgraph
     sampled_edges: int = 0
     fanouts: str = ""          # e.g. "10x5" ("full" for a None layer)
+    devices: tuple = ()        # ids of the devices holding the batch output
 
 
 def _percentile(values, q: float) -> float:

@@ -47,7 +47,9 @@ The replicas are plain engines: everything pluggable on an engine
 router via ``**engine_kwargs``, applied uniformly to every replica.
 ``meshes=`` overrides that uniformity for device placement — one mesh per
 replica, so a host's devices can be split between replicas rather than
-shared.
+shared.  Without a mesh from either, replicas spread over the visible
+devices one per device (``replica_meshes``), so N replicas on an N-chip
+host each own a chip.
 """
 
 from __future__ import annotations
@@ -56,7 +58,9 @@ import threading
 import time
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from repro.core.graph import Graph
 from repro.serving.admission import AdmissionStats
@@ -66,6 +70,17 @@ from repro.serving.report import ServeReport, build_report, slo_attainment_from
 from repro.serving.sampler import HostGraph
 
 
+def replica_meshes(num_replicas: int, axis: str = "data"):
+    """One 1-device mesh per replica, round robin over the visible devices,
+    or None when only one device is visible.  A 1-device mesh pins an
+    engine to its device (``ExecutorPool.device_args``)."""
+    devices = jax.devices()
+    if len(devices) == 1:
+        return None
+    return [Mesh(np.asarray([devices[i % len(devices)]]), (axis,))
+            for i in range(num_replicas)]
+
+
 class EngineRouter:
     """Catalog-aware request router over ``GnnServeEngine`` replicas.
 
@@ -73,7 +88,9 @@ class EngineRouter:
       num_replicas: how many engine replicas to build (>= 1).
       meshes: optional sequence of one mesh (or None) per replica, so
         replicas can own disjoint device slices; without it every replica
-        shares whatever ``mesh=`` is in ``engine_kwargs`` (usually None).
+        shares the ``mesh=`` in ``engine_kwargs`` when that is a mesh, and
+        otherwise replica i is pinned to visible device i (round robin;
+        one visible device leaves every replica meshless on it).
       engine_kwargs: forwarded verbatim to every ``GnnServeEngine``.
     """
 
@@ -88,6 +105,10 @@ class EngineRouter:
                     f"{num_replicas} replicas")
             if "mesh" in engine_kwargs:
                 raise ValueError("pass either meshes= or mesh=, not both")
+        elif engine_kwargs.get("mesh") is None:
+            engine_kwargs.pop("mesh", None)
+            meshes = replica_meshes(num_replicas,
+                                    engine_kwargs.get("shard_axis", "data"))
         self.replicas: list[GnnServeEngine] = []
         for i in range(num_replicas):
             kwargs = dict(engine_kwargs)
@@ -376,6 +397,8 @@ class EngineRouter:
                 "kernel_configs": e.pool.kernel_configs(),
                 "service_time_ms": e.service_time_ms(),
                 "pipeline": e.pipeline_stats(),
+                "devices": sorted({d for r in replica_records
+                                   for d in r.devices}),
             }
         first = self.replicas[0]
         # The merged ServeReport computes union-stream SLO attainment from

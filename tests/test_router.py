@@ -240,8 +240,10 @@ def test_merged_report_unions_replica_views():
     assert rep.replicas["replica0"]["kernel_configs"]["*"] == vars(cfg0)
     assert rep.replicas["replica1"]["kernel_configs"]["*"] == vars(cfg1)
     assert rep.replicas["replica1"]["topology"]["num_devices"] == 1
-    # Uniform replicas still report the shared view unchanged.
-    router2 = EngineRouter(2, slots=2)
+    # Uniform replicas still report the shared view unchanged.  Explicitly
+    # meshless: without meshes= a host with several devices pins replica i
+    # to device i, and the merged topology would list those 1-device meshes.
+    router2 = EngineRouter(2, slots=2, meshes=[None, None])
     router2.register("m", model, params, hot=True)
     rep2 = router2.run([("m", make_graph(400))])
     assert rep2.topology == {}
@@ -321,3 +323,20 @@ def test_meshes_length_validation():
         EngineRouter(2, meshes=[None])
     with pytest.raises(ValueError, match="not both"):
         EngineRouter(1, meshes=[None], mesh=None)
+
+
+def test_replicas_spread_over_visible_devices():
+    """Without meshes, replica i runs on visible device i (round robin):
+    every output it serves is computed there."""
+    devices = jax.devices()
+    model, params = make_model()
+    router = EngineRouter(3, slots=2)
+    router.register("m", model, params, hot=True)
+    for i in range(6):
+        router.submit("m", make_graph(500 + i))
+    router.drain()
+    for i, replica in enumerate(router.replicas):
+        want = (devices[i % len(devices)].id,)
+        assert {r.devices for r in replica.records} == {want}
+        assert router.report(1.0).replicas[f"replica{i}"]["devices"] == \
+            list(want)
