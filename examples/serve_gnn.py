@@ -37,7 +37,7 @@ to see EDF preemption protect tight-SLO models under load.
 ``--pipeline-depth N`` sets the serve-loop pipelining: 0 runs the serial
 stack-then-execute loop, N >= 1 overlaps host batch stacking with device
 execution across N executor workers (bit-exact with serial; the report
-then shows device-busy vs stack-busy overlap fractions):
+then shows exec-busy vs stack-busy overlap fractions):
 
   PYTHONPATH=src python examples/serve_gnn.py --async-loop \
       --scheduler deadline --requests 60 --pipeline-depth 2

@@ -132,6 +132,15 @@ Latency accounting uses ``time.perf_counter()`` (monotonic) throughout —
 ``time.time()`` can step backwards under clock adjustment and produce
 negative latencies.  SLO deadlines are absolute perf_counter instants
 (``t_submit + slo_ms/1e3``).
+
+Stage budget: each request's record splits submit-to-pickup into
+contiguous stages — queue ``wait_s`` (holding ``sample_s`` and
+``prep_s``), then the batch's ``stack_s``, ``device_wait_s``, ``h2d_s``
+(the copy in, waited on by itself), ``run_s`` (executor call to output on
+the host) and ``publish_s``, then ``pickup_s`` until ``result()`` hands
+the answer over.  ``report.Stage`` takes each stamp and, while a
+``jax.profiler`` trace runs, spans the stage's work as a ``gnn.<stage>``
+host event on the device trace's clock (waiting is never spanned).
 """
 
 from __future__ import annotations
@@ -166,7 +175,12 @@ from repro.serving.registry import (
     ModelEntry,
     ModelRegistry,
 )
-from repro.serving.report import RequestRecord, ServeReport, build_report
+from repro.serving.report import (
+    RequestRecord,
+    ServeReport,
+    Stage,
+    build_report,
+)
 from repro.serving.sampler import HostGraph, gcn_sample_prepare, sample_khop
 from repro.serving.scheduler import GroupState, make_scheduler
 
@@ -207,7 +221,12 @@ class _StackedBatch:
     rows: np.ndarray    # [R, Bp]
     cols: np.ndarray    # [R, Bp]
     feats: np.ndarray   # [R, padded_src, f]
-    stack_s: float
+    t_stacked: float    # perf_counter when stacking ended
+
+    @property
+    def stack_s(self) -> float:
+        """Extraction to stacked: the batch's stacking stage."""
+        return self.t_stacked - self.t_extract
 
 
 @dataclasses.dataclass
@@ -231,6 +250,7 @@ class _Pending:
     seed_rows: Optional[np.ndarray] = None  # local rows to slice results to
     num_seeds: int = 0
     sample_s: float = 0.0
+    prep_s: float = 0.0
     sampled_nodes: int = 0  # real (non-ghost) vertices in the subgraph
     sampled_edges: int = 0
     fanouts_desc: str = ""
@@ -331,6 +351,9 @@ class GnnServeEngine:
         self.cache = PreprocessCache(cache_capacity)
         self.results: dict[int, np.ndarray] = {}
         self.records: list[RequestRecord] = []
+        # rid -> (record, publish stamp) of each result not yet taken, so
+        # pickup can stamp the record's pickup_s.
+        self._unpicked: dict[int, tuple[RequestRecord, float]] = {}
         self.shed_rids: list[int] = []
         self._shed_set: set[int] = set()
         self._groups: "OrderedDict[tuple, deque[_Pending]]" = OrderedDict()
@@ -377,6 +400,12 @@ class GnnServeEngine:
         # record building and the ordered writeback of batch k proceed
         # while batch k+1 occupies the device and the stacker forms k+2.
         self._device_lock = threading.Lock()
+        # The device id batch spans carry: it tells router replicas apart.
+        device = self.pool.device
+        if device is None:
+            device = (mesh.devices.flat[0] if mesh is not None
+                      else jax.devices()[0])
+        self._device_id = int(device.id)
 
     # ------------------------------------------------------------------
     # Catalog.
@@ -438,14 +467,27 @@ class GnnServeEngine:
         # Fast path: a request the full queue will certainly reject should
         # not pay preprocessing first.  The authoritative decision is the
         # decide() inside _enqueue — atomic with the queue mutation.
+        rid = self._open_request()
+        if rid is None:
+            return None
+        t0 = time.perf_counter()
+        return self._enqueue(model_id, graph, rid, t0, t0,
+                             transform=entry_m.prepare_fn,
+                             salt=entry_m.salt, slo_ms=entry_m.slo_ms)
+
+    def _open_request(self) -> Optional[int]:
+        """Intake check and early rejection; returns the request's rid.
+
+        The rid is taken before sampling and preprocessing, so their spans
+        can carry it; a request rejected later leaves its rid unused.
+        """
         with self._cond:
             self._check_intake_open_locked()
             if self.admission.try_reject_early(self._num_waiting):
                 return None
-        t0 = time.perf_counter()
-        return self._enqueue(model_id, graph, t0,
-                             transform=entry_m.prepare_fn,
-                             salt=entry_m.salt, slo_ms=entry_m.slo_ms)
+            rid = self._next_rid
+            self._next_rid += 1
+            return rid
 
     def _check_intake_open_locked(self) -> None:
         """Fail fast on submit-after-stop.  Caller holds the engine lock.
@@ -461,36 +503,40 @@ class GnnServeEngine:
                 "closed (call start() to reopen, or drain()/step() serve "
                 "only what was already queued)")
 
-    def _enqueue(self, model_id: str, graph: Graph, t0: float,
-                 *, transform, salt: str, extra: bytes = b"",
+    def _enqueue(self, model_id: str, graph: Graph, rid: int, t0: float,
+                 t_prev: float, *, transform, salt: str, extra: bytes = b"",
                  slo_ms: Optional[float] = None,
                  nq: Optional[dict] = None) -> Optional[int]:
         """Preprocess (cached, outside the engine lock), then atomically
         admit + enqueue.  Returns the rid, or None on rejection.
 
+        ``t0`` is the submit stamp, ``t_prev`` the end of the request's
+        previous stage (sampling, or ``t0``), where ``prep_s`` starts.
         Preprocessing precedes the admission decision, so a preprocessing
         failure needs no stats rollback and can never cost a waiting
         victim its slot.
         """
-        centry, hit = self.cache.get_or_partition(
-            graph, self.cfg.v, self.cfg.n,
-            transform=transform, salt=salt, extra=extra)
-        pg = centry.pg
-        shape = centry.extras.get("shape")
-        if shape is None:
-            # Structural artifacts are feature-width-independent: cache
-            # the f=1 bucket + padded tile arrays once per structure and
-            # derive the request's full bucket from its feature width.
-            # Concurrent submitters may duplicate this work (deterministic,
-            # identical values); "padded" is published before "shape" so a
-            # reader that observes shape always finds padded.
-            shape = bucket_for(pg)
-            centry.extras["padded"] = pad_partition_to_bucket(pg, shape)
-            centry.extras["shape"] = shape
-        bucket = dataclasses.replace(
-            shape, f=next_pow2(graph.node_feat.shape[1]))
-        blocks, row, col = centry.extras["padded"]
-        feat = pad_features_to_bucket(pg, bucket, graph.node_feat)
+        with Stage("prep", rid=rid) as prep:
+            centry, hit = self.cache.get_or_partition(
+                graph, self.cfg.v, self.cfg.n,
+                transform=transform, salt=salt, extra=extra)
+            pg = centry.pg
+            shape = centry.extras.get("shape")
+            if shape is None:
+                # Structural artifacts are feature-width-independent:
+                # cache the f=1 bucket + padded tile arrays once per
+                # structure and derive the request's full bucket from its
+                # feature width.  Concurrent submitters may duplicate this
+                # work (deterministic, identical values); "padded" is
+                # published before "shape" so a reader that observes shape
+                # always finds padded.
+                shape = bucket_for(pg)
+                centry.extras["padded"] = pad_partition_to_bucket(pg, shape)
+                centry.extras["shape"] = shape
+            bucket = dataclasses.replace(
+                shape, f=next_pow2(graph.node_feat.shape[1]))
+            blocks, row, col = centry.extras["padded"]
+            feat = pad_features_to_bucket(pg, bucket, graph.node_feat)
         deadline = (t0 + slo_ms / 1e3 if slo_ms else math.inf)
         with self._cond:
             self._check_intake_open_locked()
@@ -521,8 +567,6 @@ class GnnServeEngine:
                 # Shed only now, with the replacement request viable and
                 # the queue still at its bound (same critical section).
                 self._shed_victim_locked()
-            rid = self._next_rid
-            self._next_rid += 1
             pending = _Pending(
                 rid=rid,
                 model_id=model_id,
@@ -539,6 +583,7 @@ class GnnServeEngine:
                 submit_tick=self._tick,
                 slo_ms=float(slo_ms) if slo_ms else 0.0,
                 deadline_s=deadline,
+                prep_s=prep.t1 - t_prev,
                 **(nq or {}),
             )
             self._seq += 1
@@ -602,10 +647,9 @@ class GnnServeEngine:
             raise ValueError(
                 f"model '{model_id}' expects {entry_m.f_in} features, host "
                 f"graph '{hentry.name}' carries {hg.num_features}")
-        with self._cond:
-            self._check_intake_open_locked()
-            if self.admission.try_reject_early(self._num_waiting):
-                return None
+        rid = self._open_request()
+        if rid is None:
+            return None
         t0 = time.perf_counter()
         use_fanouts = (hentry.fanouts if fanouts is None
                        else tuple(fanouts))
@@ -615,9 +659,9 @@ class GnnServeEngine:
         # bitwise restrictions of the full graph's (module docstring of
         # serving/sampler.py), which is what makes full-fanout samples
         # reproduce the full forward bit-exactly at the seeds.
-        sample = sample_khop(hg, seed_ids, use_fanouts, use_seed,
-                             align=math.lcm(self.cfg.v, self.cfg.n))
-        t_sampled = time.perf_counter()
+        with Stage("sample", rid=rid) as sampled:
+            sample = sample_khop(hg, seed_ids, use_fanouts, use_seed,
+                                 align=math.lcm(self.cfg.v, self.cfg.n))
         spf = entry_m.sample_prepare_fn
         # The transform closes over this sample's host vertices (their host
         # degrees set the edge weights), so the cache key must carry the
@@ -629,14 +673,14 @@ class GnnServeEngine:
         nq = dict(
             seed_rows=sample.seed_rows,
             num_seeds=int(len(sample.seed_rows)),
-            sample_s=t_sampled - t0,
+            sample_s=sampled.t1 - t0,
             sampled_nodes=sample.num_sampled_nodes,
             sampled_edges=sample.num_sampled_edges,
             fanouts_desc="x".join("full" if f is None else str(f)
                                   for f in use_fanouts),
         )
         return self._enqueue(
-            model_id, sample.graph, t0,
+            model_id, sample.graph, rid, t0, sampled.t1,
             transform=transform,
             salt=f"{entry_m.sample_salt}:{hg.fingerprint}",
             extra=extra, slo_ms=entry_m.slo_ms, nq=nq)
@@ -740,21 +784,23 @@ class GnnServeEngine:
         execution.
         """
         _, bucket = key
-        t0 = time.perf_counter()
         r = self.slots
         bp, v, n = bucket.num_blocks, bucket.v, bucket.n
-        blocks = np.zeros((r, bp, v, n), np.float32)
-        rows = np.zeros((r, bp), np.int32)
-        cols = np.zeros((r, bp), np.int32)
-        feats = np.zeros((r, bucket.padded_src, bucket.f), np.float32)
-        for i, p in enumerate(batch):
-            blocks[i], rows[i], cols[i] = p.blocks, p.block_row, p.block_col
-            feats[i] = p.feat
+        with Stage("stack", batch=serve_tick,
+                   device=self._device_id) as stacked:
+            blocks = np.zeros((r, bp, v, n), np.float32)
+            rows = np.zeros((r, bp), np.int32)
+            cols = np.zeros((r, bp), np.int32)
+            feats = np.zeros((r, bucket.padded_src, bucket.f), np.float32)
+            for i, p in enumerate(batch):
+                blocks[i], rows[i], cols[i] = (p.blocks, p.block_row,
+                                               p.block_col)
+                feats[i] = p.feat
         return _StackedBatch(
             key=key, batch=batch, serve_tick=serve_tick,
             t_extract=t_extract, ticket=ticket,
             blocks=blocks, rows=rows, cols=cols, feats=feats,
-            stack_s=time.perf_counter() - t0)
+            t_stacked=stacked.t1)
 
     def _run_stacked(self, sb: _StackedBatch) -> int:
         """Device stage: execute one stacked batch, then publish in group
@@ -764,56 +810,76 @@ class GnnServeEngine:
         batch = sb.batch
         entry = self.registry[model_id]
         was_warm = key in self._warm_keys
+        span = dict(batch=sb.serve_tick, device=self._device_id)
+        inputs = (sb.blocks, sb.rows, sb.cols, sb.feats)
         with self._device_lock:
             # exec_s is measured inside the lock: pure device occupancy,
-            # not time spent queued behind a peer's execution.
+            # not time spent queued behind a peer's execution.  It splits
+            # into the copy in (h2d, waited on so it is timed on its own)
+            # and the run, which ends with the output on the host.
             t_exec0 = time.perf_counter()
             exe = self.pool.executor(entry, bucket)
-            out = jax.block_until_ready(exe(*self.pool.device_args(
-                entry, sb.blocks, sb.rows, sb.cols, sb.feats)))
-            devices = tuple(sorted(d.id for d in out.devices()))
-            out = np.asarray(out)
-            t_done = time.perf_counter()
+            with Stage("h2d", **span) as h2d:
+                args = jax.block_until_ready(
+                    self.pool.device_args(entry, *inputs))
+            with Stage("run", **span) as run:
+                out = jax.block_until_ready(exe(*args))
+                devices = tuple(sorted(d.id for d in out.devices()))
+                out = np.asarray(out)
+            t_done = run.t1
             exec_s = t_done - t_exec0
+        stage_s = dict(
+            batch=sb.serve_tick,
+            stack_s=sb.stack_s,
+            device_wait_s=t_exec0 - sb.t_stacked,
+            h2d_s=h2d.t1 - t_exec0,
+            h2d_bytes=sum(a.nbytes for a in inputs),
+            run_s=t_done - h2d.t1,
+        )
 
         results: dict[int, np.ndarray] = {}
         records: list[RequestRecord] = []
-        for i, p in enumerate(batch):
-            valid = out[i][: p.graph.num_nodes]
-            if entry.task == "node":
-                # Node queries answer only their seed rows (in query order);
-                # whole-graph requests deliver every row.
-                results[p.rid] = (valid if p.seed_rows is None
-                                  else valid[p.seed_rows])
-            else:
-                results[p.rid] = np.asarray(
-                    entry.model.readout(entry.params, jnp.asarray(valid)))
-            hw_lat, hw_e = self._hardware_cost(entry, p)
-            latency = t_done - p.t_submit
-            records.append(RequestRecord(
-                rid=p.rid,
-                model_id=model_id,
-                num_nodes=p.graph.num_nodes,
-                num_edges=p.graph.num_edges,
-                bucket=bucket.describe(),
-                cache_hit=p.cache_hit,
-                latency_s=latency,
-                batch_size=len(batch),
-                wait_ticks=sb.serve_tick - p.submit_tick,
-                wait_s=sb.t_extract - p.t_submit,
-                hw_latency_s=hw_lat,
-                hw_energy_j=hw_e,
-                slo_ms=p.slo_ms,
-                deadline_s=p.deadline_s,
-                slo_met=(latency * 1e3 <= p.slo_ms if p.slo_ms else None),
-                node_query=p.seed_rows is not None,
-                num_seeds=p.num_seeds,
-                sample_s=p.sample_s,
-                sampled_nodes=p.sampled_nodes,
-                sampled_edges=p.sampled_edges,
-                fanouts=p.fanouts_desc,
-                devices=devices,
-            ))
+        # The span leaves out the ticket wait below; publish_s counts it.
+        with Stage("publish", **span):
+            for i, p in enumerate(batch):
+                valid = out[i][: p.graph.num_nodes]
+                if entry.task == "node":
+                    # Node queries answer only their seed rows (in query
+                    # order); whole-graph requests deliver every row.
+                    results[p.rid] = (valid if p.seed_rows is None
+                                      else valid[p.seed_rows])
+                else:
+                    results[p.rid] = np.asarray(entry.model.readout(
+                        entry.params, jnp.asarray(valid)))
+                hw_lat, hw_e = self._hardware_cost(entry, p)
+                latency = t_done - p.t_submit
+                records.append(RequestRecord(
+                    rid=p.rid,
+                    model_id=model_id,
+                    num_nodes=p.graph.num_nodes,
+                    num_edges=p.graph.num_edges,
+                    bucket=bucket.describe(),
+                    cache_hit=p.cache_hit,
+                    latency_s=latency,
+                    batch_size=len(batch),
+                    wait_ticks=sb.serve_tick - p.submit_tick,
+                    wait_s=sb.t_extract - p.t_submit,
+                    hw_latency_s=hw_lat,
+                    hw_energy_j=hw_e,
+                    slo_ms=p.slo_ms,
+                    deadline_s=p.deadline_s,
+                    slo_met=(latency * 1e3 <= p.slo_ms if p.slo_ms
+                             else None),
+                    node_query=p.seed_rows is not None,
+                    num_seeds=p.num_seeds,
+                    sample_s=p.sample_s,
+                    sampled_nodes=p.sampled_nodes,
+                    sampled_edges=p.sampled_edges,
+                    fanouts=p.fanouts_desc,
+                    devices=devices,
+                    prep_s=p.prep_s,
+                    **stage_s,
+                ))
         with self._cond:
             # Publish in extraction order within the group: concurrent
             # workers may *execute* same-group batches out of order (the
@@ -825,25 +891,29 @@ class GnnServeEngine:
                 if self._loop_error is not None:
                     return 0  # a peer crashed; the engine is failed anyway
                 self._write_cond.wait(timeout=0.05)
-            self._group_write[key] = sb.ticket + 1
-            self.results.update(results)
-            self.records.extend(records)
-            self._inflight -= len(batch)
-            self._stack_busy_s += sb.stack_s
-            self._exec_busy_s += exec_s
-            if was_warm:
-                # First execution of a key includes jit compilation; keep
-                # it out of the steady-state service-time model.
-                service = sb.stack_s + exec_s
-                prev = self._service_ewma.get(key)
-                self._service_ewma[key] = (
-                    service if prev is None else
-                    SERVICE_EWMA_ALPHA * service
-                    + (1.0 - SERVICE_EWMA_ALPHA) * prev)
-            else:
-                self._warm_keys.add(key)
-            self._cond.notify_all()
-            self._write_cond.notify_all()
+            with Stage("publish", **span) as published:
+                self._group_write[key] = sb.ticket + 1
+                for rec in records:
+                    rec.publish_s = published.t0 - t_done
+                    self._unpicked[rec.rid] = (rec, published.t0)
+                self.results.update(results)
+                self.records.extend(records)
+                self._inflight -= len(batch)
+                self._stack_busy_s += sb.stack_s
+                self._exec_busy_s += exec_s
+                if was_warm:
+                    # First execution of a key includes jit compilation;
+                    # keep it out of the steady-state service-time model.
+                    service = sb.stack_s + exec_s
+                    prev = self._service_ewma.get(key)
+                    self._service_ewma[key] = (
+                        service if prev is None else
+                        SERVICE_EWMA_ALPHA * service
+                        + (1.0 - SERVICE_EWMA_ALPHA) * prev)
+                else:
+                    self._warm_keys.add(key)
+                self._cond.notify_all()
+                self._write_cond.notify_all()
         return len(batch)
 
     def _execute(self, key, batch, serve_tick: int, t_extract: float,
@@ -1069,7 +1139,7 @@ class GnnServeEngine:
         with self._cond:
             while True:
                 if rid in self.results:
-                    return self.results.pop(rid)
+                    return self._pop_result_locked(rid)
                 if rid in self._shed_set:
                     raise KeyError(
                         f"request {rid} was shed by admission control")
@@ -1098,7 +1168,16 @@ class GnnServeEngine:
         output retention.
         """
         with self._cond:
-            return self.results.pop(rid)
+            return self._pop_result_locked(rid)
+
+    def _pop_result_locked(self, rid: int) -> np.ndarray:
+        """Pop one result and stamp its record's pickup_s (engine lock
+        held)."""
+        out = self.results.pop(rid)
+        rec, t_published = self._unpicked.pop(rid, (None, 0.0))
+        if rec is not None:
+            rec.pickup_s = time.perf_counter() - t_published
+        return out
 
     # ------------------------------------------------------------------
     # Closed-loop driver + accounting.
@@ -1241,6 +1320,7 @@ class GnnServeEngine:
         with self._cond:
             self.results.clear()
             self.records.clear()
+            self._unpicked.clear()
             self.shed_rids.clear()
             self._shed_set.clear()
             self._max_dropped_wait_ticks = 0

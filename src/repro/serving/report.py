@@ -22,8 +22,10 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from typing import Optional
 
+import jax
 import numpy as np
 
 
@@ -35,10 +37,12 @@ class RequestRecord:
     num_edges: int
     bucket: str
     cache_hit: bool
-    latency_s: float           # monotonic time: submit -> result materialized
+    latency_s: float           # monotonic time: submit -> device output
+                               # copied back to the host
     batch_size: int            # real requests in the batch that served it
     wait_ticks: int = 0        # serve iterations spent waiting in the queue
-    wait_s: float = 0.0        # wall seconds spent waiting in the queue
+    wait_s: float = 0.0        # submit -> batch extracted (node queries:
+                               # sampling and prep included)
     hw_latency_s: float = 0.0  # analytic GHOST inference latency
     hw_energy_j: float = 0.0
     # SLO contract (models registered with slo_ms=): 0.0 = no contract.
@@ -53,6 +57,47 @@ class RequestRecord:
     sampled_edges: int = 0
     fanouts: str = ""          # e.g. "10x5" ("full" for a None layer)
     devices: tuple = ()        # ids of the devices holding the batch output
+    # Stage budget (seconds, perf_counter; see ``Stage``).  Each stage
+    # starts where the previous one ended, so wait_s + stack_s +
+    # device_wait_s + h2d_s + run_s + publish_s + pickup_s is submit to
+    # pickup; prep_s (and sample_s) lie inside wait_s.  The batch stages
+    # carry the same value on every record of one batch.
+    batch: int = -1            # serve tick of the batch that served it
+    prep_s: float = 0.0        # cache lookup or partition, bucket padding
+    stack_s: float = 0.0       # batch: extracted -> stacked on the host
+    device_wait_s: float = 0.0  # batch: stacked -> device lock held
+    h2d_s: float = 0.0         # batch: device lock -> inputs on the device
+    h2d_bytes: int = 0         # batch: bytes handed to device_put
+    run_s: float = 0.0         # batch: executor call -> output on the host
+    publish_s: float = 0.0     # batch: readout, records, ordered publish
+    pickup_s: Optional[float] = None  # published -> result() returned
+                                      # (None until the result is taken)
+
+
+class Stage:
+    """Times one serving stage and spans it on the profiler's clock.
+
+    ``with Stage("stack", batch=tick, device=0) as st:`` leaves the
+    ``perf_counter`` stamps ``st.t0``/``st.t1`` around the body and, while
+    a ``jax.profiler`` trace runs, writes a ``gnn.stack`` host event with
+    the keyword metadata over the same interval (``rid`` for request
+    stages, ``batch`` and ``device`` for batch stages).  With no trace
+    running the annotation costs next to nothing.
+    """
+
+    __slots__ = ("_span", "t0", "t1")
+
+    def __init__(self, name: str, **meta):
+        self._span = jax.profiler.TraceAnnotation(f"gnn.{name}", **meta)
+
+    def __enter__(self) -> "Stage":
+        self._span.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._span.__exit__(*exc)
 
 
 def _percentile(values, q: float) -> float:
@@ -194,7 +239,7 @@ class ServeReport:
                            for k, v in sorted(self.service_time_ms.items()))
                + "\n" if self.service_time_ms else "")
             + (f"  pipeline depth {self.pipeline['depth']}: "
-               f"device-busy {self.pipeline.get('exec_busy_frac', 0.0):.0%} / "
+               f"exec-busy {self.pipeline.get('exec_busy_frac', 0.0):.0%} / "
                f"stack-busy {self.pipeline.get('stack_busy_frac', 0.0):.0%} "
                f"of wall clock\n" if self.pipeline else "")
             + f"  per model: {self.per_model}\n"

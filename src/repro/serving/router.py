@@ -66,7 +66,12 @@ from repro.core.graph import Graph
 from repro.serving.admission import AdmissionStats
 from repro.serving.cache import CacheStats
 from repro.serving.engine import GnnServeEngine, QueueFullError
-from repro.serving.report import ServeReport, build_report, slo_attainment_from
+from repro.serving.report import (
+    ServeReport,
+    Stage,
+    build_report,
+    slo_attainment_from,
+)
 from repro.serving.sampler import HostGraph
 
 
@@ -224,7 +229,9 @@ class EngineRouter:
         # see queue_pressure); on rejection fall back to the next (per-
         # replica admission, router-level failover).  Sort is stable, so
         # fully tied replicas keep placement order.
-        order = sorted(where, key=lambda i: self.replicas[i].queue_pressure())
+        with Stage("route"):
+            order = sorted(where,
+                           key=lambda i: self.replicas[i].queue_pressure())
         for i in order:
             local = self.replicas[i].try_submit(model_id, graph)
             if local is not None:
@@ -265,8 +272,9 @@ class EngineRouter:
             raise ValueError(
                 f"no replica holds both model '{model_id}' ({where_m}) and "
                 f"host graph '{host}' ({sorted(where_h)})")
-        order = sorted(eligible,
-                       key=lambda i: self.replicas[i].queue_pressure())
+        with Stage("route"):
+            order = sorted(eligible,
+                           key=lambda i: self.replicas[i].queue_pressure())
         for i in order:
             local = self.replicas[i].try_submit_nodes(
                 model_id, seed_ids, host=host, **kwargs)
