@@ -1,0 +1,11 @@
+"""Share of whole-graph batches stacked into a reused buffer set,
+``RequestRecord.stack_reused``, each batch counted once (%)."""
+
+
+def read(ctx):
+    per_batch = {(i, r.batch): r.stack_reused
+                 for i, recs in enumerate(ctx.replica_records) for r in recs
+                 if not r.node_query and hasattr(r, "stack_reused")}
+    if not per_batch:
+        return None
+    return 100.0 * sum(per_batch.values()) / len(per_batch)

@@ -78,7 +78,9 @@ Concurrency model and locking invariants (two-stage pipeline):
     admission estimate reads queue depth in that same section.
   * The stacker → executor handoff is a bounded ``queue.Queue`` with its
     own internal lock, never held together with the engine lock (puts
-    and gets happen outside it).
+    and gets happen outside it).  So is the free list of stacking buffer
+    sets: the stacker takes a set, the executor worker gives it back
+    once the batch's output is on the host.
   * Device execution serializes behind a dedicated *device lock*: one
     device runs one program at a time, and concurrent XLA CPU executions
     additionally thrash the shared intra-op thread pool (measurably
@@ -201,14 +203,80 @@ class QueueFullError(RuntimeError):
 SERVICE_EWMA_ALPHA = 0.25
 
 
+class _StackBuffers:
+    """One set of host arrays that batches of one shape are stacked into.
+
+    ``shape`` is ``(R, Bp, V, N, padded_src, f)``, which the bucket fixes;
+    ``filled`` is how many slots the set's last batch filled.  A refill
+    with ``k`` requests overwrites slots ``0..k-1`` and zeroes only
+    ``k..filled-1``, so the arrays equal a fresh zero-filled stack byte for
+    byte without paying for the zero-fill (or the page faults of fresh
+    memory) on every batch.
+    """
+
+    __slots__ = ("shape", "blocks", "rows", "cols", "feats", "filled")
+
+    def __init__(self, shape: tuple):
+        r, bp, v, n, src, f = shape
+        self.shape = shape
+        self.blocks = np.zeros((r, bp, v, n), np.float32)
+        self.rows = np.zeros((r, bp), np.int32)
+        self.cols = np.zeros((r, bp), np.int32)
+        self.feats = np.zeros((r, src, f), np.float32)
+        self.filled = 0
+
+    @property
+    def arrays(self) -> tuple:
+        return self.blocks, self.rows, self.cols, self.feats
+
+    def fill(self, batch) -> None:
+        for i, p in enumerate(batch):
+            self.blocks[i], self.rows[i], self.cols[i] = (p.blocks,
+                                                          p.block_row,
+                                                          p.block_col)
+            self.feats[i] = p.feat
+        for a in self.arrays:
+            a[len(batch):self.filled] = 0
+        self.filled = len(batch)
+
+
+class _StackFreeList:
+    """The engine's free ``_StackBuffers`` sets, least recently returned
+    first.
+
+    ``take`` hands out the most recently returned set of a shape (None
+    when there is none); ``give`` returns one, dropping the least recently
+    returned set beyond ``cap``.  Its lock is held with no other lock.
+    """
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self._sets: list[_StackBuffers] = []
+        self._lock = threading.Lock()
+
+    def take(self, shape: tuple) -> Optional[_StackBuffers]:
+        with self._lock:
+            for i in range(len(self._sets) - 1, -1, -1):
+                if self._sets[i].shape == shape:
+                    return self._sets.pop(i)
+        return None
+
+    def give(self, bufs: _StackBuffers) -> None:
+        with self._lock:
+            self._sets.append(bufs)
+            if len(self._sets) > self.cap:
+                del self._sets[0]
+
+
 @dataclasses.dataclass
 class _StackedBatch:
     """One extracted batch after host stacking, ready for an executor.
 
     The handoff unit of the two-stage pipeline: produced by the stacker
-    (or inline by the serial path), consumed by an executor worker.
-    ``ticket`` orders writeback within the batch's (model_id, bucket)
-    group; ``stack_s`` is the host stacking time that feeds the
+    (or inline by the serial path), consumed by an executor worker, which
+    returns ``bufs`` to the engine's free list once the output is on the
+    host.  ``ticket`` orders writeback within the batch's (model_id,
+    bucket) group; ``stack_s`` is the host stacking time that feeds the
     service-time EWMA and the pipeline busy gauges.
     """
 
@@ -217,10 +285,8 @@ class _StackedBatch:
     serve_tick: int
     t_extract: float
     ticket: int
-    blocks: np.ndarray  # [R, Bp, V, N]
-    rows: np.ndarray    # [R, Bp]
-    cols: np.ndarray    # [R, Bp]
-    feats: np.ndarray   # [R, padded_src, f]
+    bufs: _StackBuffers
+    reused: bool        # bufs came from the free list, not np.zeros
     t_stacked: float    # perf_counter when stacking ended
 
     @property
@@ -286,7 +352,9 @@ class GnnServeEngine:
         the stages).  Default 2: host stacking of batch k+1 overlaps
         device execution of batch k.  0 = the serial single-thread loop
         (stack and execute never overlap).  Tick-driven ``step``/``run``
-        are unaffected — they always serve synchronously.
+        are unaffected — they always serve synchronously.  It also caps
+        the free list of reused stacking buffer sets at ``2 *
+        pipeline_depth + 1``, the most that can be in use at once.
       service_time_admission: when True (default), a request carrying an
         SLO whose deadline cannot be met even if its group were scheduled
         immediately — per the learned expected-service-time EWMA, the
@@ -393,6 +461,12 @@ class GnnServeEngine:
         self._warm_keys: set[tuple] = set()
         self._stack_busy_s = 0.0
         self._exec_busy_s = 0.0
+        self._stack_sets_allocated = 0
+        self._stack_sets_reused = 0
+        # Stacking buffer sets: at most one being stacked, pipeline_depth
+        # in the handoff queue and pipeline_depth with executor workers
+        # are in use at once, so the free list never needs to hold more.
+        self._stack_free = _StackFreeList(2 * self.pipeline_depth + 1)
         # One device runs one program at a time: executor workers serialize
         # the jitted call + block_until_ready behind this lock (concurrent
         # XLA CPU executions thrash the shared intra-op thread pool — worse
@@ -781,25 +855,22 @@ class GnnServeEngine:
 
         Runs outside every lock (stacker thread, or inline on the serial
         path) — this is the work the pipeline overlaps with device
-        execution.
+        execution.  Fills a free buffer set of the batch's shapes, or a
+        new zero-filled one when the free list has none.
         """
         _, bucket = key
-        r = self.slots
-        bp, v, n = bucket.num_blocks, bucket.v, bucket.n
-        with Stage("stack", batch=serve_tick,
-                   device=self._device_id) as stacked:
-            blocks = np.zeros((r, bp, v, n), np.float32)
-            rows = np.zeros((r, bp), np.int32)
-            cols = np.zeros((r, bp), np.int32)
-            feats = np.zeros((r, bucket.padded_src, bucket.f), np.float32)
-            for i, p in enumerate(batch):
-                blocks[i], rows[i], cols[i] = (p.blocks, p.block_row,
-                                               p.block_col)
-                feats[i] = p.feat
+        shape = (self.slots, bucket.num_blocks, bucket.v, bucket.n,
+                 bucket.padded_src, bucket.f)
+        bufs = self._stack_free.take(shape)
+        reused = bufs is not None
+        with Stage("stack", batch=serve_tick, device=self._device_id,
+                   reused=reused) as stacked:
+            if bufs is None:
+                bufs = _StackBuffers(shape)
+            bufs.fill(batch)
         return _StackedBatch(
             key=key, batch=batch, serve_tick=serve_tick,
-            t_extract=t_extract, ticket=ticket,
-            blocks=blocks, rows=rows, cols=cols, feats=feats,
+            t_extract=t_extract, ticket=ticket, bufs=bufs, reused=reused,
             t_stacked=stacked.t1)
 
     def _run_stacked(self, sb: _StackedBatch) -> int:
@@ -811,26 +882,34 @@ class GnnServeEngine:
         entry = self.registry[model_id]
         was_warm = key in self._warm_keys
         span = dict(batch=sb.serve_tick, device=self._device_id)
-        inputs = (sb.blocks, sb.rows, sb.cols, sb.feats)
-        with self._device_lock:
-            # exec_s is measured inside the lock: pure device occupancy,
-            # not time spent queued behind a peer's execution.  It splits
-            # into the copy in (h2d, waited on so it is timed on its own)
-            # and the run, which ends with the output on the host.
-            t_exec0 = time.perf_counter()
-            exe = self.pool.executor(entry, bucket)
-            with Stage("h2d", **span) as h2d:
-                args = jax.block_until_ready(
-                    self.pool.device_args(entry, *inputs))
-            with Stage("run", **span) as run:
-                out = jax.block_until_ready(exe(*args))
-                devices = tuple(sorted(d.id for d in out.devices()))
-                out = np.asarray(out)
-            t_done = run.t1
-            exec_s = t_done - t_exec0
+        inputs = sb.bufs.arrays
+        try:
+            with self._device_lock:
+                # exec_s is measured inside the lock: pure device
+                # occupancy, not time spent queued behind a peer's
+                # execution.  It splits into the copy in (h2d, waited on
+                # so it is timed on its own) and the run, which ends with
+                # the output on the host.
+                t_exec0 = time.perf_counter()
+                exe = self.pool.executor(entry, bucket)
+                with Stage("h2d", **span) as h2d:
+                    args = jax.block_until_ready(
+                        self.pool.device_args(entry, *inputs))
+                with Stage("run", **span) as run:
+                    out = jax.block_until_ready(exe(*args))
+                    devices = tuple(sorted(d.id for d in out.devices()))
+                    out = np.asarray(out)
+                t_done = run.t1
+                exec_s = t_done - t_exec0
+        finally:
+            # The output is on the host (or the run failed): nothing reads
+            # the stacked arrays any more, even where device_put aliased
+            # them, so the set can take the next batch.
+            self._stack_free.give(sb.bufs)
         stage_s = dict(
             batch=sb.serve_tick,
             stack_s=sb.stack_s,
+            stack_reused=sb.reused,
             device_wait_s=t_exec0 - sb.t_stacked,
             h2d_s=h2d.t1 - t_exec0,
             h2d_bytes=sum(a.nbytes for a in inputs),
@@ -901,6 +980,10 @@ class GnnServeEngine:
                 self._inflight -= len(batch)
                 self._stack_busy_s += sb.stack_s
                 self._exec_busy_s += exec_s
+                if sb.reused:
+                    self._stack_sets_reused += 1
+                else:
+                    self._stack_sets_allocated += 1
                 if was_warm:
                     # First execution of a key includes jit compilation;
                     # keep it out of the steady-state service-time model.
@@ -1291,11 +1374,15 @@ class GnnServeEngine:
         """Configured depth + cumulative per-stage busy seconds (a report
         turns these into busy *fractions* of the measured wall clock;
         exec is device occupancy — the device lock serializes execution —
-        so overlap shows as exec near 1.0 with stack-busy nonzero)."""
+        so overlap shows as exec near 1.0 with stack-busy nonzero), and
+        how many published batches were stacked into a newly allocated
+        or a reused buffer set."""
         with self._cond:
             return {"depth": self.pipeline_depth,
                     "stack_busy_s": self._stack_busy_s,
-                    "exec_busy_s": self._exec_busy_s}
+                    "exec_busy_s": self._exec_busy_s,
+                    "stack_sets_allocated": self._stack_sets_allocated,
+                    "stack_sets_reused": self._stack_sets_reused}
 
     def report(self, wall_s: float) -> ServeReport:
         wait_ticks, wait_s = self.queue_wait_gauges()
@@ -1327,5 +1414,7 @@ class GnnServeEngine:
             self._max_dropped_wait_s = 0.0
             self._stack_busy_s = 0.0
             self._exec_busy_s = 0.0
+            self._stack_sets_allocated = 0
+            self._stack_sets_reused = 0
             self.cache.stats = CacheStats()
             self.admission.stats = AdmissionStats()

@@ -65,6 +65,7 @@ class RequestRecord:
     batch: int = -1            # serve tick of the batch that served it
     prep_s: float = 0.0        # cache lookup or partition, bucket padding
     stack_s: float = 0.0       # batch: extracted -> stacked on the host
+    stack_reused: bool = False  # batch: stacked into a reused buffer set
     device_wait_s: float = 0.0  # batch: stacked -> device lock held
     h2d_s: float = 0.0         # batch: device lock -> inputs on the device
     h2d_bytes: int = 0         # batch: bytes handed to device_put

@@ -443,14 +443,15 @@ class EngineRouter:
         return {key: sums[key] / counts[key] for key in sums}
 
     def _merged_pipeline(self) -> dict:
-        """Summed per-stage busy seconds over all replicas (the router's
-        replicas share one configured depth; per-replica splits stay in
-        ``ServeReport.replicas``)."""
+        """Summed per-stage busy seconds and stacking buffer-set counts
+        over all replicas (the router's replicas share one configured
+        depth; per-replica splits stay in ``ServeReport.replicas``)."""
         stats = [e.pipeline_stats() for e in self.replicas]
         return {
             "depth": stats[0]["depth"],
-            "stack_busy_s": sum(s["stack_busy_s"] for s in stats),
-            "exec_busy_s": sum(s["exec_busy_s"] for s in stats),
+            **{k: sum(s[k] for s in stats)
+               for k in ("stack_busy_s", "exec_busy_s",
+                         "stack_sets_allocated", "stack_sets_reused")},
         }
 
     def _merged_kernel_configs(self) -> dict:
