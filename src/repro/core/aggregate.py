@@ -75,7 +75,8 @@ def active_aggregate_backend() -> str:
 
 @contextlib.contextmanager
 def aggregate_backend(name: str):
-    """Route blocked SUM/MEAN aggregation through the chosen backend.
+    """Route blocked SUM/MEAN aggregation and GAT attention through the
+    chosen backend.
 
     The selection is read at trace time, so wrapping a jit'd call site routes
     every blocked aggregation inside that trace.  MAX always uses the jnp
@@ -672,6 +673,16 @@ def aggregate_combine_blocked(
     return dense_combine(h, w, bias, activation, quantized)
 
 
+def to_dst_rows(x: jax.Array, pad_dst: int) -> jax.Array:
+    """Pad-or-slice a source-padded [G_src*N, ...] array to [G_dst*V, ...].
+
+    Node i is row i on both sides, so row i of the result is node i's."""
+    need = pad_dst - x.shape[0]
+    if need > 0:
+        x = jnp.pad(x, ((0, need),) + ((0, 0),) * (x.ndim - 1))
+    return x[:pad_dst]
+
+
 def attention_aggregate_blocked(
     bg: BlockedGraph,
     values_padded: jax.Array,   # [G_src*N, H, F]  (already W-transformed, per head)
@@ -679,18 +690,47 @@ def attention_aggregate_blocked(
     dst_scores: jax.Array,      # [G_dst*V, H]     a_dst . (W h_v)
     negative_slope: float = 0.2,
 ) -> jax.Array:
-    """GAT-style masked-softmax aggregation on the blocked adjacency.
+    """GAT masked-softmax aggregation over N(v) u {v} on the blocked adjacency.
 
-    Computes, per head h:  out[v] = sum_u softmax_u(leaky_relu(e_uv)) val[u]
-    with e_uv = dst_scores[v] + src_scores[u], masked to edges, using a
-    numerically-stable two-pass (segment-max then segment-sum) over tiles —
-    the blocked analogue of GHOST's GAT pipeline (Section 3.4.2, Fig. 6b).
+    Computes, per head:  out[v] = sum_{u in N(v) u {v}} alpha_vu val[u],
+    alpha = softmax_u(leaky_relu(dst_scores[v] + src_scores[u])) — GHOST's
+    GAT pipeline (Section 3.4.2, Fig. 6b).  The self term is each row's own
+    score and value (node i is row i on both sides).  Tile values carry
+    edge multiplicity (duplicate edges accumulate at partition time), so a
+    duplicate weighs twice, as in the edge-list softmax.  A loop already in
+    the graph is not an extra neighbour: the self term stands for it once,
+    so loop entries are masked out.
+
+    On the ``pallas`` and ``pallas_fused`` backends this runs the blocked
+    edge-softmax kernel (``kernels/attention_block_spmm.py``: one online
+    softmax per destination row in VMEM, never a ``[B, V, N, H]`` array).
+    The ``jnp`` path below is its oracle: a two-pass (segment-max then
+    segment-sum) over dense per-tile logits.
 
     Returns [G_dst*V, H, F].
     """
+    if active_aggregate_backend() in _PALLAS_BACKENDS:
+        # Lazy import: kernels.ops imports core.partition (cycle guard).
+        from repro.kernels.ops import attention_block_spmm_padded
+
+        return attention_block_spmm_padded(
+            bg.blocks, bg.block_row, bg.block_col, values_padded, src_scores,
+            dst_scores, bg.num_dst_groups, negative_slope=negative_slope)
+
     heads = values_padded.shape[1]
     f = values_padded.shape[2]
-    mask = bg.blocks != 0                                              # [B,V,N]
+    pad_dst = bg.num_dst_groups * bg.v
+    dst_id = (bg.block_row[:, None, None] * bg.v
+              + jnp.arange(bg.v)[None, :, None])
+    src_id = (bg.block_col[:, None, None] * bg.n
+              + jnp.arange(bg.n)[None, None, :])
+    mask = (bg.blocks != 0) & (dst_id != src_id)                       # [B,V,N]
+
+    self_logit = jax.nn.leaky_relu(
+        dst_scores + to_dst_rows(src_scores, pad_dst), negative_slope)
+    self_logit = self_logit.reshape(bg.num_dst_groups, bg.v, heads)
+    self_vals = to_dst_rows(values_padded, pad_dst).reshape(
+        bg.num_dst_groups, bg.v, heads, f)
 
     s_src = src_scores.reshape(bg.num_src_groups, bg.n, heads)[bg.block_col]   # [B,N,H]
     s_dst = dst_scores.reshape(bg.num_dst_groups, bg.v, heads)[bg.block_row]   # [B,V,H]
@@ -699,26 +739,26 @@ def attention_aggregate_blocked(
     neg = jnp.asarray(-1e30, logits.dtype)
     logits = jnp.where(mask[..., None], logits, neg)
 
-    # Pass 1: per-destination-row running max across tiles.
+    # Pass 1: per-destination-row max over tiles and the self term.
     tile_max = logits.max(axis=2)                                      # [B,V,H]
     row_max = jax.ops.segment_max(tile_max, bg.block_row, num_segments=bg.num_dst_groups)
-    row_max = jnp.maximum(row_max, -1e30)                              # isolated rows
+    row_max = jnp.maximum(row_max, self_logit)                         # finite
     m = row_max[bg.block_row][:, :, None, :]                           # [B,V,1,H]
 
-    # Pass 2: exp-sum and weighted value sum.  Tile values carry edge
-    # multiplicity (duplicate edges accumulate at partition time), so p is
-    # scaled by them — matching the edge-list softmax on multigraphs.
+    # Pass 2: exp-sum and weighted value sum, scaled by multiplicity.
     mult = bg.blocks[..., None]                                        # [B,V,N,1]
     p = jnp.where(mask[..., None], mult * jnp.exp(logits - m), 0.0)    # [B,V,N,H]
-    denom_partial = p.sum(axis=2)                                      # [B,V,H]
-    denom = jax.ops.segment_sum(denom_partial, bg.block_row, num_segments=bg.num_dst_groups)
+    p_self = jnp.exp(self_logit - row_max)                             # [G,V,H]
+    denom = jax.ops.segment_sum(p.sum(axis=2), bg.block_row,
+                                num_segments=bg.num_dst_groups) + p_self
 
     vals = values_padded.reshape(bg.num_src_groups, bg.n, heads, f)[bg.block_col]  # [B,N,H,F]
     num_partial = jnp.einsum("bvnh,bnhf->bvhf", p, vals)               # [B,V,H,F]
     num = jax.ops.segment_sum(num_partial, bg.block_row, num_segments=bg.num_dst_groups)
+    num = num + p_self[..., None] * self_vals
 
-    out = num / jnp.maximum(denom, 1e-30)[..., None]
-    return out.reshape(bg.num_dst_groups * bg.v, heads, f)
+    out = num / denom[..., None]
+    return out.reshape(pad_dst, heads, f)
 
 
 # ---------------------------------------------------------------------------

@@ -3,9 +3,13 @@
 Each conv exposes the two execution backends:
 
   apply         — edge-list backend (training / oracle)
-  apply_blocked — GHOST V x N blocked backend (serving; numerically equal)
+  apply_blocked — GHOST V x N blocked backend (serving)
 
-and an optional quantized combine (the photonic 8-bit sign-split MVM).
+and an optional quantized combine (the photonic 8-bit sign-split MVM).  The
+two backends compute the same function in float32, summed in another order,
+so they agree to a tolerance of the output's scale, not bit for bit: a few
+ULP on the CPU, and on the TPU, where a float32 matmul is one bfloat16
+pass, that pass's rounding.
 
 Every ``apply_blocked`` aggregate+combine pair routes through
 ``core.aggregate.aggregate_combine_blocked``: the static order planner
@@ -35,7 +39,10 @@ from repro.core.aggregate import (
     aggregate_edges,
     attention_aggregate_blocked,
     dense_combine,
+    to_dst_rows,
 )
+
+
 def init_linear(key, f_in: int, f_out: int, bias: bool = True) -> dict:
     wkey, _ = jax.random.split(key)
     scale = (2.0 / (f_in + f_out)) ** 0.5
@@ -47,14 +54,6 @@ def init_linear(key, f_in: int, f_out: int, bias: bool = True) -> dict:
 
 def _linear(x, p, quantized: bool):
     return dense_combine(x, p["w"], p.get("b"), quantized=quantized)
-
-
-def _to_dst_rows(x, pad_dst: int):
-    """Pad-or-slice a source-padded [G_src*N, ...] array to [G_dst*V, ...]."""
-    need = pad_dst - x.shape[0]
-    if need > 0:
-        x = jnp.pad(x, ((0, need),) + ((0, 0),) * (x.ndim - 1))
-    return x[:pad_dst]
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +107,7 @@ class SAGEConv:
         h = aggregate_combine_blocked(
             bg, feat_padded, p["neigh"]["w"], reduce=ReduceOp.MEAN,
             quantized=quantized)
-        self_feat = _to_dst_rows(feat_padded, bg.num_dst_groups * bg.v)
+        self_feat = to_dst_rows(feat_padded, bg.num_dst_groups * bg.v)
         return _linear(self_feat, p["self"], quantized) + h
 
 
@@ -142,7 +141,7 @@ class GINConv:
 
     @staticmethod
     def apply_blocked(p, bg: BlockedGraph, feat_padded, quantized=False):
-        self_feat = _to_dst_rows(feat_padded, bg.num_dst_groups * bg.v)
+        self_feat = to_dst_rows(feat_padded, bg.num_dst_groups * bg.v)
         if quantized or active_aggregate_backend() != "pallas_fused":
             # Unfused form.  Quantized: the int8 MVM quantizes its input, so
             # W1 cannot be distributed over the (self, aggregate) sum
@@ -167,12 +166,24 @@ class GINConv:
 
 
 # ---------------------------------------------------------------------------
-# GAT — transform-first: e_uv = leaky_relu(a . [W h_v || W h_u]), softmax,
-# weighted sum.  Multi-head with concat (hidden layers) or mean (output).
+# GAT — transform-first: e_uv = leaky_relu(a . [W h_v || W h_u]), softmax
+# over N(v) u {v}, weighted sum.  Multi-head with concat (hidden layers) or
+# mean (output).
 # ---------------------------------------------------------------------------
 
 
 class GATConv:
+    """One GAT layer (Velickovic et al., arXiv:1710.10903, eq. 1-4).
+
+    Each vertex attends over its in-neighbours and itself, N(v) u {v}, as
+    published.  A duplicate edge weighs as many times as it appears.  A loop
+    already in the graph is not an extra neighbour: the self term stands for
+    it, once, so loops in the edge list (or tile entries whose source is the
+    destination) are ignored.  ``apply`` (float32 edge list) is the oracle of
+    ``apply_blocked``, which runs the blocked edge-softmax kernel on the
+    Pallas backends (``core.aggregate.attention_aggregate_blocked``).
+    """
+
     @staticmethod
     def init(key, f_in, f_out, heads=1):
         k1, k2, k3 = jax.random.split(key, 3)
@@ -200,16 +211,21 @@ class GATConv:
         wh = GATConv._project(p, feat, quantized)                # [N,H,F]
         s_src = (wh * p["a_src"]).sum(-1)                        # [N,H]
         s_dst = (wh * p["a_dst"]).sum(-1)
+        edge = (edge_src != edge_dst)[:, None]                   # loops out
         logits = jax.nn.leaky_relu(
             s_dst[edge_dst] + s_src[edge_src], negative_slope
         )                                                        # [E,H]
+        logits = jnp.where(edge, logits, -1e30)
+        self_logit = jax.nn.leaky_relu(s_dst + s_src, negative_slope)
         m = jax.ops.segment_max(logits, edge_dst, num_segments=num_nodes)
-        m = jnp.where(jnp.isfinite(m), m, 0.0)
-        z = jnp.exp(logits - m[edge_dst])
+        m = jnp.maximum(m, self_logit)                           # [N,H]
+        z = jnp.where(edge, jnp.exp(logits - m[edge_dst]), 0.0)
+        z_self = jnp.exp(self_logit - m)
         denom = jax.ops.segment_sum(z, edge_dst, num_segments=num_nodes)
-        alpha = z / jnp.maximum(denom[edge_dst], 1e-30)          # [E,H]
-        msgs = alpha[..., None] * wh[edge_src]                   # [E,H,F]
+        denom = denom + z_self
+        msgs = z[..., None] * wh[edge_src]                       # [E,H,F]
         out = jax.ops.segment_sum(msgs, edge_dst, num_segments=num_nodes)
+        out = (out + z_self[..., None] * wh) / denom[..., None]
         out = out + p["b"]
         if concat:
             return out.reshape(num_nodes, -1)
@@ -223,7 +239,7 @@ class GATConv:
         s_dst = (wh * p["a_dst"]).sum(-1)
         pad_dst = bg.num_dst_groups * bg.v
         out = attention_aggregate_blocked(
-            bg, wh, s_src, _to_dst_rows(s_dst, pad_dst), negative_slope
+            bg, wh, s_src, to_dst_rows(s_dst, pad_dst), negative_slope
         )                                                        # [pad_dst,H,F]
         out = out + p["b"]
         if concat:

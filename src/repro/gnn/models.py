@@ -2,7 +2,8 @@
 
   GCN        2 layers                       (node classification)
   GraphSAGE  2 layers, mean aggregation     (node classification)
-  GAT        2 layers, 8 heads then 1 head  (node classification)
+  GAT        2 layers, 8 heads then 1 head  (node classification),
+             attending over N(v) u {v} as published
   GIN        2 convs x 4-layer MLPs = 8 MLP layers + sum-pool readout
              (graph classification)
 
@@ -11,7 +12,8 @@ Every model supports:
   apply(params, *edge arrays)       edge-list backend (training/oracle)
   apply_blocked(params, bg, featp)  GHOST blocked backend (serving)
 and a `quantized=` flag that routes every combine through the photonic 8-bit
-sign-split MVM.
+sign-split MVM.  The two backends agree to a float32 tolerance of the
+output's scale, not bit for bit (see ``gnn.layers``).
 """
 
 from __future__ import annotations
@@ -83,6 +85,17 @@ class GAT:
     num_classes: int
     hidden: int = 8
     heads: int = 8
+
+    # Layers that run the blocked attention aggregation (engine counters).
+    attention_layers = 2
+
+    @staticmethod
+    def attended_entries(graph) -> int:
+        """Entries one attention layer attends over in ``graph``: its
+        edges that are not loops, plus one self term per vertex."""
+        loops = int(np.count_nonzero(np.asarray(graph.edge_src)
+                                     == np.asarray(graph.edge_dst)))
+        return graph.num_edges - loops + graph.num_nodes
 
     def init(self, key):
         k1, k2 = jax.random.split(key)

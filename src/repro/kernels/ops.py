@@ -24,7 +24,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.aggregate import to_dst_rows
 from repro.core.partition import PartitionedGraph
+from repro.kernels.attention_block_spmm import attention_block_spmm
 from repro.kernels.block_spmm import block_spmm
 from repro.kernels.fused_block_spmm import (
     apply_epilogue_activation,
@@ -175,6 +177,54 @@ def fused_block_spmm_padded(
     fill = apply_epilogue_activation(bias_row.astype(jnp.float32),
                                      activation).astype(out.dtype)
     return jnp.where(jnp.repeat(visited, v)[:, None], out, fill[None, :])
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_dst_groups", "negative_slope", "lane", "interpret"),
+)
+def attention_block_spmm_padded(
+    blocks: jax.Array,       # [B, V, N] CSR-row-sorted tiles
+    block_row: jax.Array,    # [B] int32, non-decreasing
+    block_col: jax.Array,    # [B] int32
+    values: jax.Array,       # [G_src * N, H, F] projected values W h
+    src_scores: jax.Array,   # [G_src * N, H]
+    dst_scores: jax.Array,   # [G_dst * V, H]
+    num_dst_groups: int,
+    negative_slope: float = 0.2,
+    lane: int = 128,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """attention_block_spmm with the self term, lane and tile padding.
+
+    Builds each destination row's self term from its own source-side row
+    (node i is row i on both sides), pads H*F to ``lane`` multiples and
+    V/N to sublane multiples, runs the kernel, slices the padding off, and
+    gives rows of never-visited groups their self value: a softmax over
+    the self term alone.  Returns [G_dst * V, H, F].
+    """
+    interpret = auto_interpret() if interpret is None else interpret
+    v, n = blocks.shape[1:]
+    heads, f = values.shape[1:]
+    pad_dst = num_dst_groups * v
+    vals2d = values.reshape(values.shape[0], heads * f)
+    self_vals = to_dst_rows(vals2d, pad_dst)
+    self_logits = jax.nn.leaky_relu(
+        to_dst_rows(src_scores, pad_dst) + dst_scores, negative_slope)
+    # Source scores per source group, heads on the sublanes: [G_src, H, N8].
+    ssrc = _pad_groups(src_scores, n).reshape(
+        -1, n + (-n) % SUBLANE, heads).transpose(0, 2, 1)
+    out = attention_block_spmm(
+        _pad_tiles(blocks), block_row, block_col,
+        _pad_groups(_pad_to(vals2d, 1, lane), n), ssrc,
+        _pad_groups(dst_scores, v), _pad_groups(self_logits, v),
+        _pad_groups(_pad_to(self_vals, 1, lane), v),
+        num_dst_groups, heads, f, v, n, negative_slope=negative_slope,
+        interpret=interpret)
+    out = _unpad_groups(out, v)[:, :heads * f]
+    visited = jnp.zeros((num_dst_groups,), jnp.bool_).at[block_row].set(True)
+    out = jnp.where(jnp.repeat(visited, v)[:, None], out, self_vals)
+    return out.reshape(pad_dst, heads, f)
 
 
 def aggregate_blocked_kernel(pg_or_bg, feat_padded: jax.Array,

@@ -882,6 +882,7 @@ class GnnServeEngine:
         entry = self.registry[model_id]
         was_warm = key in self._warm_keys
         span = dict(batch=sb.serve_tick, device=self._device_id)
+        attn = self._attention_counts(entry, bucket, batch)
         inputs = sb.bufs.arrays
         try:
             with self._device_lock:
@@ -895,7 +896,7 @@ class GnnServeEngine:
                 with Stage("h2d", **span) as h2d:
                     args = jax.block_until_ready(
                         self.pool.device_args(entry, *inputs))
-                with Stage("run", **span) as run:
+                with Stage("run", **span, **attn) as run:
                     out = jax.block_until_ready(exe(*args))
                     devices = tuple(sorted(d.id for d in out.devices()))
                     out = np.asarray(out)
@@ -914,6 +915,7 @@ class GnnServeEngine:
             h2d_s=h2d.t1 - t_exec0,
             h2d_bytes=sum(a.nbytes for a in inputs),
             run_s=t_done - h2d.t1,
+            **attn,
         )
 
         results: dict[int, np.ndarray] = {}
@@ -998,6 +1000,17 @@ class GnnServeEngine:
                 self._cond.notify_all()
                 self._write_cond.notify_all()
         return len(batch)
+
+    def _attention_counts(self, entry: ModelEntry, bucket: Bucket,
+                          batch) -> dict:
+        """``attn_edges``/``attn_entries`` of a batch of an attention
+        model (see ``RequestRecord``), or {} for any other model."""
+        layers = getattr(entry.model, "attention_layers", 0)
+        if not layers:
+            return {}
+        edges = sum(entry.model.attended_entries(p.graph) for p in batch)
+        sweep = self.slots * bucket.num_blocks * bucket.v * bucket.n
+        return dict(attn_edges=layers * edges, attn_entries=layers * sweep)
 
     def _execute(self, key, batch, serve_tick: int, t_extract: float,
                  ticket: int) -> int:

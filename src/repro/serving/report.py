@@ -71,6 +71,13 @@ class RequestRecord:
     h2d_bytes: int = 0         # batch: bytes handed to device_put
     run_s: float = 0.0         # batch: executor call -> output on the host
     publish_s: float = 0.0     # batch: readout, records, ordered publish
+    # Attention models (GAT) only, 0 elsewhere; batch values, summed over
+    # the model's attention layers:
+    attn_edges: int = 0        # real attended entries of the batch's
+                               # requests: edges that are not loops, plus
+                               # one self term per vertex
+    attn_entries: int = 0      # tile entries the attention kernel swept:
+                               # slots x tiles x V x N, empty slots too
     pickup_s: Optional[float] = None  # published -> result() returned
                                       # (None until the result is taken)
 

@@ -68,7 +68,8 @@ def test_weighted_sum_gcn_norm():
 
 
 def test_attention_aggregate_matches_segment_softmax():
-    """Blocked GAT softmax-aggregation == explicit edge-list computation."""
+    """Blocked GAT softmax-aggregation == explicit edge-list computation
+    over N(v) u {v}: loops in the graph dropped, one self term per vertex."""
     g = make_graph(2, 30, 120, 4)
     heads, f = 3, 4
     rng = np.random.default_rng(0)
@@ -76,17 +77,20 @@ def test_attention_aggregate_matches_segment_softmax():
     s_src = rng.standard_normal((g.num_nodes, heads)).astype(np.float32)
     s_dst = rng.standard_normal((g.num_nodes, heads)).astype(np.float32)
 
-    # edge-list reference
+    # edge-list reference on the attended entries
     import jax
+    keep = g.edge_src != g.edge_dst
+    nodes = np.arange(g.num_nodes)
+    src = jnp.asarray(np.concatenate([g.edge_src[keep], nodes]))
+    dst = jnp.asarray(np.concatenate([g.edge_dst[keep], nodes]))
     logits = jax.nn.leaky_relu(
-        jnp.asarray(s_dst)[g.edge_dst] + jnp.asarray(s_src)[g.edge_src], 0.2)
-    m = jax.ops.segment_max(logits, jnp.asarray(g.edge_dst), num_segments=g.num_nodes)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    z = jnp.exp(logits - m[g.edge_dst])
-    denom = jax.ops.segment_sum(z, jnp.asarray(g.edge_dst), num_segments=g.num_nodes)
-    alpha = z / jnp.maximum(denom[g.edge_dst], 1e-30)
-    ref = jax.ops.segment_sum(alpha[..., None] * jnp.asarray(vals)[g.edge_src],
-                              jnp.asarray(g.edge_dst), num_segments=g.num_nodes)
+        jnp.asarray(s_dst)[dst] + jnp.asarray(s_src)[src], 0.2)
+    m = jax.ops.segment_max(logits, dst, num_segments=g.num_nodes)
+    z = jnp.exp(logits - m[dst])
+    denom = jax.ops.segment_sum(z, dst, num_segments=g.num_nodes)
+    alpha = z / denom[dst]
+    ref = jax.ops.segment_sum(alpha[..., None] * jnp.asarray(vals)[src],
+                              dst, num_segments=g.num_nodes)
 
     pg = partition_graph(g, v=7, n=9)
     bg = to_blocked(pg)

@@ -124,6 +124,37 @@ def test_executor_compiles(one_chip, chip_lowering, bucket, f_in, order):
     _assert_kernel(pool.lower(entry, bucket, sharding=one_chip).compile())
 
 
+@pytest.mark.parametrize("v", TILES)
+@pytest.mark.parametrize("heads,f", [pytest.param(8, 8, id="h8f8"),
+                                     pytest.param(1, 7, id="h1f7")])
+def test_attention_block_spmm_compiles(one_chip, v, heads, f):
+    """GAT's edge-softmax kernel at Cora's published head shapes."""
+    b, g = 16, 4
+    args = (_struct(one_chip, (b, v, v)),
+            _struct(one_chip, (b,), jnp.int32),
+            _struct(one_chip, (b,), jnp.int32),
+            _struct(one_chip, (g * v, heads, f)),
+            _struct(one_chip, (g * v, heads)),
+            _struct(one_chip, (g * v, heads)))
+    fn = jax.jit(lambda *a: ops.attention_block_spmm_padded(
+        *a, g, interpret=False))
+    _assert_kernel(fn.lower(*args).compile())
+
+
+@pytest.mark.parametrize("bucket,f_in", BUCKETS)
+def test_gat_executor_compiles(one_chip, chip_lowering, bucket, f_in):
+    """The vmapped GAT executor at its published widths (8 heads of 8,
+    then one head of 7) on pallas_fused, as the gat-cora cell serves it:
+    its attention runs in the kernel, never in the jnp oracle."""
+    model = build_model("gat", f_in, 7, hidden=8, heads=8)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    entry = ModelRegistry().register("gat", model, params, task="node")
+    pool = ExecutorPool(slots=4, backend="pallas_fused")
+    text = pool.lower(entry, bucket, sharding=one_chip).compile().as_text()
+    # One kernel per GAT layer.
+    assert text.count("tpu_custom_call") >= 2
+
+
 def test_quantized_matmul_compiles(one_chip):
     x = _struct(one_chip, (256, 256))
     w = _struct(one_chip, (256, 256))
