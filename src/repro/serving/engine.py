@@ -86,7 +86,11 @@ Concurrency model and locking invariants (two-stage pipeline):
     additionally thrash the shared intra-op thread pool (measurably
     slower than serial).  Workers therefore overlap only *host* work —
     readout, record building, ordered writeback — around the serialized
-    device stage.  The device lock is held with no other lock.
+    device stage.  The copy in runs in that stage too: a batch with empty
+    slots writes its filled ones into the pool's device slot set of its
+    shape, which the next batch of the shape overwrites, so no run can
+    read a set while it is written.  The device lock is held with no
+    other lock but the pool's own, which guards its dictionaries.
   * Group-ordered writeback: extraction stamps each batch with a
     monotone per-``(model_id, bucket)`` *ticket* (under the engine
     lock); an executor worker publishes its batch only when the group's
@@ -138,9 +142,9 @@ negative latencies.  SLO deadlines are absolute perf_counter instants
 Stage budget: each request's record splits submit-to-pickup into
 contiguous stages — queue ``wait_s`` (holding ``sample_s`` and
 ``prep_s``), then the batch's ``stack_s``, ``device_wait_s``, ``h2d_s``
-(the copy in, waited on by itself), ``run_s`` (executor call to output on
-the host) and ``publish_s``, then ``pickup_s`` until ``result()`` hands
-the answer over.  ``report.Stage`` takes each stamp and, while a
+(the copy in of the filled slots, waited on by itself), ``run_s``
+(executor call to output on the host) and ``publish_s``, then
+``pickup_s`` until ``result()`` hands the answer over.  ``report.Stage`` takes each stamp and, while a
 ``jax.profiler`` trace runs, spans the stage's work as a ``gnn.<stage>``
 host event on the device trace's clock (waiting is never spanned).
 """
@@ -171,11 +175,13 @@ from repro.serving.bucketing import (
 )
 from repro.serving.cache import CacheStats, PreprocessCache
 from repro.serving.registry import (
+    STACK_DTYPES,
     ExecutorPool,
     HostGraphCatalog,
     HostGraphEntry,
     ModelEntry,
     ModelRegistry,
+    stack_shapes,
 )
 from repro.serving.report import (
     RequestRecord,
@@ -217,12 +223,10 @@ class _StackBuffers:
     __slots__ = ("shape", "blocks", "rows", "cols", "feats", "filled")
 
     def __init__(self, shape: tuple):
-        r, bp, v, n, src, f = shape
         self.shape = shape
-        self.blocks = np.zeros((r, bp, v, n), np.float32)
-        self.rows = np.zeros((r, bp), np.int32)
-        self.cols = np.zeros((r, bp), np.int32)
-        self.feats = np.zeros((r, src, f), np.float32)
+        self.blocks, self.rows, self.cols, self.feats = (
+            np.zeros(s, d) for s, d in zip(stack_shapes(shape),
+                                           STACK_DTYPES))
         self.filled = 0
 
     @property
@@ -858,9 +862,7 @@ class GnnServeEngine:
         execution.  Fills a free buffer set of the batch's shapes, or a
         new zero-filled one when the free list has none.
         """
-        _, bucket = key
-        shape = (self.slots, bucket.num_blocks, bucket.v, bucket.n,
-                 bucket.padded_src, bucket.f)
+        shape = self.pool.stack_shape(key[1])
         bufs = self._stack_free.take(shape)
         reused = bufs is not None
         with Stage("stack", batch=serve_tick, device=self._device_id,
@@ -884,18 +886,22 @@ class GnnServeEngine:
         span = dict(batch=sb.serve_tick, device=self._device_id)
         attn = self._attention_counts(entry, bucket, batch)
         inputs = sb.bufs.arrays
+        slots = self.pool.copied_slots(len(batch))
         try:
             with self._device_lock:
                 # exec_s is measured inside the lock: pure device
                 # occupancy, not time spent queued behind a peer's
                 # execution.  It splits into the copy in (h2d, waited on
                 # so it is timed on its own) and the run, which ends with
-                # the output on the host.
+                # the output on the host.  The copy in takes only the
+                # filled slots where the pool writes them into its device
+                # slot set; the lock keeps a run from reading a set while
+                # the next batch writes it.
                 t_exec0 = time.perf_counter()
                 exe = self.pool.executor(entry, bucket)
-                with Stage("h2d", **span) as h2d:
-                    args = jax.block_until_ready(
-                        self.pool.device_args(entry, *inputs))
+                with Stage("h2d", **span, slots=slots) as h2d:
+                    args = jax.block_until_ready(self.pool.device_args(
+                        entry, *inputs, filled=len(batch)))
                 with Stage("run", **span, **attn) as run:
                     out = jax.block_until_ready(exe(*args))
                     devices = tuple(sorted(d.id for d in out.devices()))
@@ -913,7 +919,8 @@ class GnnServeEngine:
             stack_reused=sb.reused,
             device_wait_s=t_exec0 - sb.t_stacked,
             h2d_s=h2d.t1 - t_exec0,
-            h2d_bytes=sum(a.nbytes for a in inputs),
+            h2d_bytes=sum(a[:slots].nbytes for a in inputs),
+            h2d_slots=slots,
             run_s=t_done - h2d.t1,
             **attn,
         )

@@ -19,6 +19,11 @@ slice back to the model's true ``f_in`` inside the trace — the zero padding
 columns never enter the arithmetic, so per-request outputs stay bit-exact
 vs the unbatched ``apply_blocked`` while models with different feature
 widths share the host-side batching machinery.
+
+A batch that fills ``k`` of its ``R`` slots copies in only those ``k``:
+the pool writes them into a device-resident ``[R, ...]`` slot set of the
+batch's shape, whose other slots are zero, so the executor reads the same
+bytes as a whole zero-filled stack (see ``ExecutorPool.device_args``).
 """
 
 from __future__ import annotations
@@ -30,6 +35,8 @@ from typing import Callable, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.aggregate import (
     AGGREGATE_BACKENDS,
@@ -220,6 +227,42 @@ class HostGraphEntry:
     rng_seed: int = 0
 
 
+# A stacked batch's arrays, in executor argument order: blocks, rows,
+# cols, feats.
+STACK_DTYPES = (jnp.float32, jnp.int32, jnp.int32, jnp.float32)
+# Device slot sets the pool keeps, least recently used dropped beyond: one
+# set holds a whole [R, ...] stack on the device, so this bounds their
+# memory as buckets come and go.
+MAX_SLOT_SETS = 4
+
+
+def stack_shapes(shape: tuple) -> tuple:
+    """The four array shapes of a stack of ``shape = (R, Bp, V, N,
+    padded_src, f)``: blocks, rows, cols, feats."""
+    r, bp, v, n, src, f = shape
+    return (r, bp, v, n), (r, bp), (r, bp), (r, src, f)
+
+
+class _SlotSet:
+    """One stacked shape's executor inputs, resident on the device.
+
+    ``arrays`` are blocks, rows, cols and feats at ``[R, ...]``; every
+    slot at or past ``filled`` (the occupancy of the set's last batch) is
+    zero, as in ``engine._StackBuffers``.  ``zero`` is one zero slot of
+    each array: the source that clears a slot without a copy in.
+    """
+
+    __slots__ = ("arrays", "zero", "filled")
+
+    def __init__(self, shape: tuple, device):
+        shapes = stack_shapes(shape)
+        self.arrays = tuple(jax.device_put(np.zeros(s, d), device)
+                            for s, d in zip(shapes, STACK_DTYPES))
+        self.zero = tuple(jax.device_put(np.zeros(s[1:], d), device)
+                          for s, d in zip(shapes, STACK_DTYPES))
+        self.filled = 0
+
+
 class ExecutorPool:
     """Compiled vmapped blocked forwards, one per (model_id, bucket).
 
@@ -239,6 +282,10 @@ class ExecutorPool:
     warm-started from the tuner's persisted cache), and only then builds
     the real jit with a lookup resolver.  Timing never happens inside a
     trace, and a warm cache makes the pre-pass pure lookup.
+
+    Where the pool places each batch on one device (no mesh, or a
+    1-device mesh), it also keeps one device slot set per stacked shape
+    and one compiled slot writer per shape; see ``device_args``.
     """
 
     def __init__(self, slots: int, backend: str, *,
@@ -267,9 +314,15 @@ class ExecutorPool:
         self.device = (mesh.devices.flat[0]
                        if mesh is not None and mesh.devices.size == 1
                        else None)
+        # A multi-device mesh shards each batch inside the trace, so only
+        # a pool that places batches on one device copies in by slot.
+        self.copies_slots = mesh is None or mesh.devices.size == 1
         self._placed_params: dict[str, object] = {}
         self._executors: dict[tuple[str, Bucket], Callable] = {}
         self._trace_count = 0
+        self._writers: dict[tuple, Callable] = {}
+        self._writer_trace_count = 0
+        self._slot_sets: "OrderedDict[tuple, _SlotSet]" = OrderedDict()
         # Pipelined serving runs several executor workers; the get-or-build
         # below must not race (a lost race would double-compile and skew
         # trace_count).  Build time under the lock is acceptable: it is
@@ -311,6 +364,25 @@ class ExecutorPool:
     def trace_count(self) -> int:
         return self._trace_count
 
+    @property
+    def writer_trace_count(self) -> int:
+        """Slot writer traces: one per stacked shape, never one per slot
+        index or occupancy."""
+        return self._writer_trace_count
+
+    def stack_shape(self, bucket: Bucket) -> tuple:
+        """``(R, Bp, V, N, padded_src, f)``: the shape a bucket's batches
+        are stacked at."""
+        return (self.slots, bucket.num_blocks, bucket.v, bucket.n,
+                bucket.padded_src, bucket.f)
+
+    def copied_slots(self, filled: Optional[int]) -> int:
+        """Slots copied in for a batch that fills ``filled`` of them: only
+        those on a one-device pool with empty slots, else all ``R``."""
+        if filled is None or filled >= self.slots or not self.copies_slots:
+            return self.slots
+        return filled
+
     def __len__(self) -> int:
         return len(self._executors)
 
@@ -319,19 +391,80 @@ class ExecutorPool:
         with self._lock:
             return list(self._executors)
 
-    def device_args(self, entry: ModelEntry, *batch) -> tuple:
+    def device_args(self, entry: ModelEntry, *batch,
+                    filled: Optional[int] = None) -> tuple:
         """``(params, *batch)`` as executor arguments, on the pool's device.
 
-        A model's params are placed once, every batch on each call.  A
+        A model's params are placed once, the batch on each call.  A
         pinned pool (1-device mesh) commits them to its device; otherwise
         ``self.device`` is None and they go to JAX's default device, where
         a multi-device mesh shards them inside the trace.
+
+        ``batch`` is a whole host stack (blocks, rows, cols, feats at
+        ``[R, ...]``) whose slots from ``filled`` on are zero.  Where
+        ``copied_slots(filled)`` is under ``R``, only slots ``0..filled-1``
+        are copied in, each written into the shape's device slot set,
+        and the slots the set's last batch filled past them are cleared
+        from its zero slot: the set then holds the stack's bytes exactly.
+        Callers serialize the batches of one pool from this call through
+        their run (the engine's device lock), since the next batch of the
+        shape writes into the arrays returned here.
         """
         params = self._placed_params.get(entry.model_id)
         if params is None:
             params = self._placed_params.setdefault(
                 entry.model_id, jax.device_put(entry.params, self.device))
-        return (params, *jax.device_put(batch, self.device))
+        k = self.copied_slots(filled)
+        if k == self.slots:
+            return (params, *jax.device_put(batch, self.device))
+        blocks, _, _, feats = batch
+        shape = (*blocks.shape, *feats.shape[1:])
+        with self._lock:
+            writer = self._writer(shape)
+            # Taken out while written: a write that raises leaves no
+            # half-written set behind, and the next batch starts afresh.
+            slot_set = self._slot_sets.pop(shape, None)
+        if slot_set is None:
+            slot_set = _SlotSet(shape, self.device)
+        slots = jax.device_put([tuple(a[i] for a in batch)
+                                for i in range(k)], self.device)
+        arrays = slot_set.arrays
+        for i in range(max(k, slot_set.filled)):
+            arrays = writer(arrays, np.int32(i),
+                            slots[i] if i < k else slot_set.zero)
+        slot_set.arrays, slot_set.filled = arrays, k
+        with self._lock:
+            self._slot_sets[shape] = slot_set
+            while len(self._slot_sets) > MAX_SLOT_SETS:
+                self._slot_sets.popitem(last=False)
+        return (params, *arrays)
+
+    def _writer(self, shape: tuple) -> Callable:
+        """The compiled slot writer of a stacked shape (caller holds the
+        lock): ``(arrays, i, slot) -> arrays`` with slot ``i`` of each
+        array replaced by ``slot``'s, in place (the arrays are donated).
+        ``i`` is traced, so one program serves every slot index."""
+        writer = self._writers.get(shape)
+        if writer is not None:
+            return writer
+
+        def write(arrays, i, slot):
+            self._writer_trace_count += 1  # runs at trace time only
+            return tuple(jax.lax.dynamic_update_index_in_dim(a, s, i, 0)
+                         for a, s in zip(arrays, slot))
+
+        sharding = (None if self.device is None
+                    else SingleDeviceSharding(self.device))
+        structs = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                   for s, d in zip(stack_shapes(shape), STACK_DTYPES)]
+        writer = self._writers[shape] = jax.jit(
+            write, donate_argnums=0).lower(
+            tuple(structs),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding),
+            tuple(jax.ShapeDtypeStruct(t.shape[1:], t.dtype,
+                                       sharding=sharding)
+                  for t in structs)).compile()
+        return writer
 
     def lower(self, entry: ModelEntry, bucket: Bucket, sharding=None):
         """Lower the (model, bucket) executor at its batch shapes without
@@ -341,22 +474,22 @@ class ExecutorPool:
         def struct(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-        r = self.slots
         params = jax.tree.map(lambda p: struct(p.shape, p.dtype),
                               entry.params)
         return self.executor(entry, bucket).lower(
-            params,
-            struct((r, bucket.num_blocks, bucket.v, bucket.n), jnp.float32),
-            struct((r, bucket.num_blocks), jnp.int32),
-            struct((r, bucket.num_blocks), jnp.int32),
-            struct((r, bucket.padded_src, bucket.f), jnp.float32))
+            params, *(struct(s, d) for s, d in zip(
+                stack_shapes(self.stack_shape(bucket)), STACK_DTYPES)))
 
     def executor(self, entry: ModelEntry, bucket: Bucket) -> Callable:
+        """The (model, bucket) executor, built on first use together with
+        its shape's slot writer, so neither compiles while serving."""
         key = (entry.model_id, bucket)
         with self._lock:
             exe = self._executors.get(key)
             if exe is None:
                 exe = self._executors[key] = self._build(entry, bucket)
+                if self.copies_slots:
+                    self._writer(self.stack_shape(bucket))
             return exe
 
     def _build(self, entry: ModelEntry, bucket: Bucket) -> Callable:

@@ -68,7 +68,11 @@ class RequestRecord:
     stack_reused: bool = False  # batch: stacked into a reused buffer set
     device_wait_s: float = 0.0  # batch: stacked -> device lock held
     h2d_s: float = 0.0         # batch: device lock -> inputs on the device
-    h2d_bytes: int = 0         # batch: bytes handed to device_put
+    h2d_bytes: int = 0         # batch: bytes of its stack handed to
+                               # device_put (the zero fill of a new
+                               # device slot set is not counted)
+    h2d_slots: int = 0         # batch: slots copied in (the filled ones
+                               # where the pool copies by slot, else all)
     run_s: float = 0.0         # batch: executor call -> output on the host
     publish_s: float = 0.0     # batch: readout, records, ordered publish
     # Attention models (GAT) only, 0 elsewhere; batch values, summed over

@@ -163,12 +163,12 @@ def test_a_set_is_never_handed_out_while_its_batch_is_unrun():
         current.sb = sb
         return real_run(sb)
 
-    def slow_device_args(entry, *batch):
+    def slow_device_args(entry, *batch, **kwargs):
         time.sleep(0.01)   # let the stacker run ahead
         _, want = snapshots[id(current.sb)]
         for got, w in zip(batch, want):
             np.testing.assert_array_equal(got, w)
-        return device_args(entry, *batch)
+        return device_args(entry, *batch, **kwargs)
 
     eng._stack, eng._run_stacked = stack, run
     eng._stack_free.give = give
@@ -239,10 +239,14 @@ def reused_batches(records):
 def test_records_and_pipeline_stats_agree():
     eng = make_engine(2)
     eng.start()
-    rids = [eng.submit("m", make_graph(i, SMALL if i % 4 else LARGE))
-            for i in range(20)]
-    for rid in rids:
-        eng.result(rid, timeout=120)
+    # Two waves: the first wave's sets are all back on the free list
+    # before the second is submitted, so the second must reuse some (in
+    # one wave every batch could be in flight before any set returns).
+    for wave in (range(10), range(10, 20)):
+        rids = [eng.submit("m", make_graph(i, SMALL if i % 4 else LARGE))
+                for i in wave]
+        for rid in rids:
+            eng.result(rid, timeout=120)
     eng.stop()
     reused, batches = reused_batches(eng.records)
     stats = eng.pipeline_stats()

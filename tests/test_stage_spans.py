@@ -7,8 +7,8 @@ The load-bearing claims:
   * the stages are contiguous: ``wait_s + stack_s + device_wait_s +
     h2d_s + run_s + publish_s + pickup_s`` is the request's submit to
     pickup, with ``sample_s`` and ``prep_s`` inside ``wait_s``;
-  * ``h2d_bytes`` is what the executor handed to ``device_put``: the whole
-    stacked batch, empty slots included;
+  * ``h2d_bytes`` is what the executor handed to ``device_put``: the
+    batch's filled slots (one-device pools copy in no empty slot);
   * a ``jax.profiler`` trace holds one ``gnn.<stage>`` host event per
     stage, with ``rid`` on request stages and ``batch``/``device`` on
     batch stages; a router adds ``gnn.route``.
@@ -132,10 +132,11 @@ def test_h2d_bytes_counts_the_stacked_arrays(depth, kind):
     handed = []
     device_args = eng.pool.device_args
 
-    def spy(entry, *batch):
+    def spy(entry, *batch, filled):
         assert all(a.shape[0] == SLOTS for a in batch)
-        handed.append(sum(a.nbytes for a in batch))
-        return device_args(entry, *batch)
+        # The pool has one device: it copies in the filled slots alone.
+        handed.append(sum(a[:filled].nbytes for a in batch))
+        return device_args(entry, *batch, filled=filled)
 
     eng.pool.device_args = spy
     eng.start()

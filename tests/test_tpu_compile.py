@@ -3,8 +3,9 @@
 The TPU compiler is installed even where no chip is attached: it compiles
 for a *described* v5e, and refuses what the chip would refuse (a block whose
 second-minor dim is not a multiple of 8, a vector shape Mosaic cannot lay
-out) where interpret mode passes.  Each test asserts that the compiled
-program holds the Pallas kernel (``tpu_custom_call``).  Nothing runs.
+out) where interpret mode passes.  Each kernel test asserts that the
+compiled program holds the Pallas kernel (``tpu_custom_call``); the slot
+writer's, that it updates in place.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -13,8 +14,9 @@ this file.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.gnn import build_model
 from repro.kernels import ops
@@ -153,6 +155,24 @@ def test_gat_executor_compiles(one_chip, chip_lowering, bucket, f_in):
     text = pool.lower(entry, bucket, sharding=one_chip).compile().as_text()
     # One kernel per GAT layer.
     assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("bucket,f_in", [
+    *BUCKETS,
+    pytest.param(Bucket(num_blocks=256, num_dst_groups=256,
+                        num_src_groups=256, v=20, n=20, f=1024), 602,
+                 id="reddit"),
+])
+def test_slot_writer_compiles_in_place(topo, bucket, f_in):
+    """The slot writer of a pool pinned to one chip updates its donated
+    slot set in place: every output aliases its input and the program
+    needs no scratch copy of the stack."""
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    pool = ExecutorPool(slots=8, backend="pallas_fused", mesh=mesh)
+    with pool._lock:
+        writer = pool._writer(pool.stack_shape(bucket))
+    assert writer.as_text().count("may-alias") == 4
+    assert writer.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_quantized_matmul_compiles(one_chip):
